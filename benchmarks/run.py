@@ -779,6 +779,9 @@ def main(argv=None) -> None:
                          "table4 (OOC engine) comparisons")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.smoke and args.only is None:
         args.only = ["peel"]

@@ -294,6 +294,14 @@ class OocStats:
     edits_applied: int = 0    # maintenance edits applied (maintain.py)
     maintain_levels: int = 0  # per-level region peels run by maintenance
     affected_edges: int = 0   # Σ candidate edges over maintenance levels
+    pallas_lanes: int = 0     # lanes dispatched to the fused Pallas kernel
+    #                           (a retried dispatch counts again)
+    xla_lanes: int = 0        # lanes dispatched to the XLA frontier engines
+    pallas_max_lanes: int = 0  # widest lane batch one fused-kernel call took
+    lane_shards: int = 0      # distinct lane slices the devices held in the
+    #                           first bucket split over a mesh (its output
+    #                           sharding; 1 = every device held every lane)
+    lanes_per_shard: int = 0  # lane rows each device held in that bucket
 
     @property
     def prefetch_hit_rate(self) -> float:
@@ -344,6 +352,18 @@ class OocStats:
         self.tri_est += batch.tri_est
         self.ns_sweeps += 1        # build_partition_batch does exactly one
         #                            whole-graph NS sweep + triangle routing
+
+    def count_lanes(self, handle) -> None:
+        """Credit one dispatch's lanes to the engine it took
+        (``PendingPeel.engine``); host short-circuits count as neither.
+        The first mesh-split bucket also records its lane split."""
+        if handle.engine == "pallas":
+            self.pallas_lanes += handle.lanes
+            self.pallas_max_lanes = max(self.pallas_max_lanes, handle.lanes)
+        elif handle.engine == "xla":
+            self.xla_lanes += handle.lanes
+        if handle.lane_split is not None and not self.lane_shards:
+            self.lane_shards, self.lanes_per_shard = handle.lane_split
 
     def as_dict(self) -> Dict[str, int]:
         """JSON-safe counter snapshot (the journal's metadata form)."""
@@ -828,6 +848,7 @@ def _retry_stage1_round(eng: _Engine, stats: OocStats, shape_cache,
                         fault_ctx={"stage": 1, "round": round_idx,
                                    "bucket": bi, "sub": si, "retry": split})
                     stats.compiles += int(h.new_compile)
+                    stats.count_lanes(h)
                     stats.batches += 1
                     phi_b, _ = h.result()
                     fold_bucket(round_idx, sub, ids, np.asarray(phi_b))
@@ -941,6 +962,7 @@ def _lower_bounding_batched(n, edges, budget, part_fn, mesh=None,
                             fault_ctx={"stage": 1, "round": round_idx,
                                        "bucket": bi, "retry": 0})
                         stats.compiles += int(h.new_compile)
+                        stats.count_lanes(h)
                         handles.append(h)
                     stats.sharded_rounds += int(
                         any(h.sharded for h in handles))
@@ -1269,6 +1291,7 @@ def bottom_up_decompose(
                     mesh_axis=eng.mesh_axis, kernel=eng.kernel,
                     fault_ctx={"stage": 2, "k": int(k), "retry": 0})
                 stats.compiles += int(handle.new_compile)
+                stats.count_lanes(handle)
                 stats.batches += 1
                 stats.sharded_rounds += int(handle.sharded)
             except Exception as exc:
@@ -1292,6 +1315,7 @@ def bottom_up_decompose(
                         fault_ctx={"stage": 2, "k": int(_k),
                                    "retry": retry})
                     stats.compiles += int(h.new_compile)
+                    stats.count_lanes(h)
                     stats.batches += 1
                     _, rem = h.result()
                     return rem

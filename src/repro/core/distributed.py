@@ -58,16 +58,10 @@ _BIG = jnp.int32(np.iinfo(np.int32).max // 2)
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map moved; check_vma was
-    check_rep).  Trip counts are data-dependent per shard, so both checks
-    are disabled."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the replication check off: trip counts are
+    data-dependent per shard."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 Long out-of-core decompositions fail in a handful of well-defined places:
 a device peel OOMs at dispatch, a :class:`~repro.core.peel.PendingPeel`
-finalize surfaces an ``XlaRuntimeError`` one round late, a checkpoint write
+finalize surfaces a ``JaxRuntimeError`` one round late, a checkpoint write
 is torn by a crash, or the process dies outright between rounds.  Testing
 the recovery paths by monkeypatching each call site separately sprawls and
 drifts; this module instead names the injection sites once —
@@ -39,7 +39,7 @@ just its fallout.
 
 Fault kinds:
 
-* ``"oom"``      — raise an ``XlaRuntimeError`` whose message carries
+* ``"oom"``      — raise a ``JaxRuntimeError`` whose message carries
   ``RESOURCE_EXHAUSTED`` (exactly what a real device OOM surfaces);
   classified retryable by :func:`is_retryable`, so the drivers' rebuild /
   lane-split / degrade ladder engages.
@@ -68,11 +68,7 @@ import os
 import signal
 from typing import Any, Dict, List, Optional
 
-try:  # the real device-error type, so retry classification matches production
-    from jaxlib.xla_extension import XlaRuntimeError
-except Exception:  # pragma: no cover - jaxlib always present in this image
-    class XlaRuntimeError(RuntimeError):
-        """Stand-in when jaxlib is unavailable."""
+import jax
 
 # site names (any string is accepted; these are the ones the engines report)
 DISPATCH = "dispatch"
@@ -93,20 +89,18 @@ class InjectedFault(RuntimeError):
 
 
 def make_oom(site: str, ctx: Dict[str, Any]) -> BaseException:
-    """An ``XlaRuntimeError`` indistinguishable (to the retry classifier)
-    from a real device allocation failure."""
-    msg = (f"RESOURCE_EXHAUSTED: injected device OOM at site={site!r} "
-           f"ctx={ctx!r}")
-    try:
-        return XlaRuntimeError(msg)
-    except Exception:  # pragma: no cover - XlaRuntimeError takes a message
-        return RuntimeError(msg)
+    """A ``jax.errors.JaxRuntimeError`` — the type real device failures
+    raise — indistinguishable (to the retry classifier) from a real device
+    allocation failure."""
+    return jax.errors.JaxRuntimeError(
+        f"RESOURCE_EXHAUSTED: injected device OOM at site={site!r} "
+        f"ctx={ctx!r}")
 
 
 def is_retryable(exc: BaseException) -> bool:
     """Whether a failure is worth a rebuild-and-retry (DESIGN.md §12).
 
-    Retryable: device resource exhaustion — an ``XlaRuntimeError`` (or any
+    Retryable: device resource exhaustion — a ``JaxRuntimeError`` (or any
     ``RuntimeError``) whose message carries a RESOURCE_EXHAUSTED / OOM
     marker.  Shrinking the dispatch (lane split, mesh drop, smaller rounds)
     can genuinely fix these.  Everything else — :class:`InjectedFault`,

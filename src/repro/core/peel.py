@@ -454,13 +454,25 @@ class PendingPeel:
     fault-injection site (DESIGN.md §12): an injected failure there lands
     inside the consume path exactly like a real asynchronous device error
     surfacing at block time, and poisons the handle the same way.
+
+    ``engine`` names the peel engine the dispatch took ("pallas", "xla", or
+    "host" for the triangle-free short-circuit) and ``lanes`` its lane
+    width, so drivers can count which lanes went to the fused kernel.
+    ``lane_split`` is ``(lane_shards, lanes_per_shard)`` for a bucket whose
+    lanes were split over a mesh, read off the output's sharding: the
+    number of distinct lane slices the devices hold and the lane rows of
+    each; ``None`` for a single-device dispatch.
     """
 
     def __init__(self, finalize, new_compile: bool, sharded: bool = False,
-                 fault_ctx: Optional[dict] = None):
+                 fault_ctx: Optional[dict] = None, engine: str = "xla",
+                 lanes: int = 1, lane_split: Optional[tuple] = None):
         self._finalize = finalize
         self.new_compile = bool(new_compile)
         self.sharded = bool(sharded)
+        self.engine = engine
+        self.lanes = int(lanes)
+        self.lane_split = lane_split
         self._fault_ctx = fault_ctx
         self._out = None
         self._error = None
@@ -553,7 +565,8 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         phi = np.where(np.asarray(alive_b), 2, 0).astype(np.int32)
         st = np.zeros(tris_np.shape[:1] + (N_STATS,), np.int32)
         if not blocking:
-            return PendingPeel(lambda: (phi, st), False, fault_ctx=fault_ctx)
+            return PendingPeel(lambda: (phi, st), False, fault_ctx=fault_ctx,
+                               engine="host", lanes=len(phi))
         return phi, st, False
     # frontier capacities: local decompositions peel every lane to EMPTY,
     # so total frontier throughput matters more than per-round width — the
@@ -592,8 +605,13 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
             return np.asarray(phi_d)[:B], np.asarray(st_d)[:B]
 
         if not blocking:
+            shape = tuple(phi_d.shape)
+            slices = phi_d.sharding.devices_indices_map(shape).values()
+            lane_split = (len({s[0].indices(shape[0]) for s in slices}),
+                          int(phi_d.sharding.shard_shape(shape)[0]))
             return PendingPeel(_finish, new, sharded=True,
-                               fault_ctx=fault_ctx)
+                               fault_ctx=fault_ctx, lanes=B,
+                               lane_split=lane_split)
         phi, st = _finish()
         return phi, st, new
     from repro.kernels.frontier_peel import ops as frontier_ops
@@ -613,7 +631,8 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         if not blocking:
             return PendingPeel(
                 lambda: (np.asarray(phi_d), np.asarray(st_d)), new,
-                fault_ctx=fault_ctx)
+                fault_ctx=fault_ctx, engine="pallas",
+                lanes=int(sup_b.shape[0]))
         return np.asarray(phi_d), np.asarray(st_d), new
     key = (sup_b.shape, tris_b.shape, cap_f, cap_t)
     new = shape_cache is not None and key not in shape_cache
@@ -625,7 +644,7 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         cap_f=cap_f, cap_t=cap_t)
     if not blocking:
         return PendingPeel(lambda: (np.asarray(phi), np.asarray(st)), new,
-                           fault_ctx=fault_ctx)
+                           fault_ctx=fault_ctx, lanes=int(sup_b.shape[0]))
     return np.asarray(phi), np.asarray(st), new
 
 
@@ -688,7 +707,7 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
         alive_out = alive0 & ~removed
         if not blocking:
             return PendingPeel(lambda: (alive_out, removed), False,
-                               fault_ctx=fault_ctx)
+                               fault_ctx=fault_ctx, engine="host")
         return alive_out, removed, False
     # pow4 capacities: consecutive k levels shrink the candidate slowly, so
     # the coarser grid makes most of a run's peels share one compiled shape
@@ -765,7 +784,8 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
             return alive, alive0 & ~alive
 
         if not blocking:
-            return PendingPeel(_finish_fused, new, fault_ctx=fault_ctx)
+            return PendingPeel(_finish_fused, new, fault_ctx=fault_ctx,
+                               engine="pallas")
         alive, removed = _finish_fused()
         return alive, removed, new
     indptr, tids = triangle_incidence_np(tris_p, cap_e)

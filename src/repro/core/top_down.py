@@ -329,6 +329,7 @@ def top_down_decompose(
                 mesh_axis=eng.mesh_axis, kernel=eng.kernel,
                 fault_ctx={"stage": "td", "k": int(k), "retry": 0})
             stats.compiles += int(handle.new_compile)
+            stats.count_lanes(handle)
             stats.batches += 1
             stats.sharded_rounds += int(handle.sharded)
         except Exception as exc:
@@ -352,6 +353,7 @@ def top_down_decompose(
                     mesh_axis=e.mesh_axis, kernel=e.kernel,
                     fault_ctx={"stage": "td", "k": int(_k), "retry": retry})
                 stats.compiles += int(h.new_compile)
+                stats.count_lanes(h)
                 stats.batches += 1
                 stats.sharded_rounds += int(h.sharded)
                 s, _ = h.result()

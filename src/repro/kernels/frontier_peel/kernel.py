@@ -39,12 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific scratch shapes; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)  # noqa: E731
-except Exception:  # pragma: no cover - fallback for pallas builds without tpu
-    _SCRATCH = lambda shape: pl.pallas_core.ScratchShape(shape, jnp.float32)  # type: ignore[attr-defined]  # noqa: E731
+from jax.experimental.pallas import tpu as pltpu
 
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # leave headroom below the ~16 MB/core
 DEFAULT_TILE_CANDIDATES = (128, 256, 512, 1024)
@@ -53,31 +48,44 @@ DEFAULT_TILE_CANDIDATES = (128, 256, 512, 1024)
 def kernel_vmem_bytes(cap_e: int, bt: int) -> int:
     """Conservative VMEM working set of one (lane, tile) kernel step.
 
-    Five int32 edge-state rows + one f32 accumulator row (6 * cap_e words),
-    the streamed (bt, 3) triangle tile, and the transient (bt, cap_e) f32
-    one-hot used for the gather/scatter matmuls — counted twice for the
-    operand copy the MXU pipeline holds in flight.
+    Five int32 edge-state rows + one f32 accumulator row (6 * cap_e words);
+    the streamed (bt, 3) triangle tile, double-buffered and padded to a
+    128-lane tile; four (bt, 1) f32 per-triangle columns, lane-padded the
+    same way; and the transient (bt, cap_e) f32 one-hot used for the
+    gather/scatter matmuls — counted twice for the operand copy the MXU
+    pipeline holds in flight.  The TPU compiler's own scoped-VMEM need
+    stays under this bound (``tests/test_tpu_compile.py`` compiles the
+    kernel with the bound as its VMEM limit).
     """
     edge_rows = 6 * cap_e * 4
-    tri_tile = bt * 3 * 4
+    tri_tile = 2 * bt * 128 * 4
+    columns = 4 * bt * 128 * 4
     onehot = 2 * bt * cap_e * 4
-    return edge_rows + tri_tile + onehot
+    return edge_rows + tri_tile + columns + onehot
 
 
 def _round_kernel(sup_ref, alive_ref, rm_ref, tris_ref,
                   sup_out_ref, alive_out_ref, dec_ref):
-    """Grid (B, T // bt): lane i's edge state resident, tile j streamed."""
+    """Grid (B, T // bt): lane i's edge state resident, tile j streamed.
+
+    Edge-state refs are (1, 1, E) blocks of the (B, 1, E) lane-major rows;
+    index 0 drops the lane dim, leaving the (1, E) row the body works on.
+    """
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         dec_ref[...] = jnp.zeros_like(dec_ref)
 
-    cap_e = sup_ref.shape[1]
+    cap_e = sup_ref.shape[2]
     bt = tris_ref.shape[1]
-    alive_f = alive_ref[...].astype(jnp.float32).reshape(cap_e, 1)
-    rm_f = rm_ref[...].astype(jnp.float32).reshape(cap_e, 1)
+    alive_f = alive_ref[0].astype(jnp.float32)
+    rm_f = rm_ref[0].astype(jnp.float32)
     alive2_f = alive_f * (1.0 - rm_f)
+
+    def gather(oh, row):
+        return jax.lax.dot_general(oh, row, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
 
     cols = jax.lax.broadcasted_iota(jnp.int32, (bt, cap_e), 1)
 
@@ -91,25 +99,22 @@ def _round_kernel(sup_ref, alive_ref, rm_ref, tris_ref,
     surv = jnp.ones((bt, 1), jnp.float32)
     for c in range(3):
         oh = onehot(c)
-        live = live * jnp.dot(oh, alive_f,
-                              preferred_element_type=jnp.float32)
-        surv = surv * (1.0 - jnp.dot(oh, rm_f,
-                                     preferred_element_type=jnp.float32))
+        live = live * gather(oh, alive_f)
+        surv = surv * (1.0 - gather(oh, rm_f))
     died = live * (1.0 - surv)                                   # (bt, 1)
 
     # pass 2: each died triangle decrements each surviving corner once
     for c in range(3):
         oh = onehot(c)
-        corner_alive2 = jnp.dot(oh, alive2_f,
-                                preferred_element_type=jnp.float32)
+        corner_alive2 = gather(oh, alive2_f)
         contrib = (died * corner_alive2).reshape(1, bt)
         dec_ref[...] += jnp.dot(contrib, oh,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        sup_out_ref[...] = sup_ref[...] - dec_ref[...].astype(jnp.int32)
-        alive_out_ref[...] = alive_ref[...] * (1 - rm_ref[...])
+        sup_out_ref[0] = sup_ref[0] - dec_ref[...].astype(jnp.int32)
+        alive_out_ref[0] = alive_ref[0] * (1 - rm_ref[0])
 
 
 def fused_round(sup, alive, rm, tris, *, bt: int = 256,
@@ -121,30 +126,30 @@ def fused_round(sup, alive, rm, tris, *, bt: int = 256,
     per-lane drop slot E.  Returns (sup', alive') as (B, E) int32.
 
     ``interpret=True`` runs the Pallas interpreter (CPU test path);
-    compiled mode targets TPU (jax 0.4.37 has no CPU Pallas lowering).
+    compiled mode targets TPU (Pallas has no CPU lowering).
+
+    Inside the call the rows are laid out (B, 1, E) so that every edge-state
+    block, (1, 1, E), equals the array in its last two dims — the Mosaic
+    tiling rule a (1, E) block of a (B, E) array breaks for B > 1.
     """
     B, cap_e = sup.shape
     T = tris.shape[1]
     if T % bt:
         raise ValueError(f"tile {bt} must divide triangle count {T}")
     grid = (B, T // bt)
-    lane = lambda i, j: (i, 0)  # noqa: E731
-    return pl.pallas_call(
+    row = pl.BlockSpec((1, 1, cap_e), lambda i, j: (i, 0, 0))
+    sup_out, alive_out = pl.pallas_call(
         _round_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, cap_e), lane),
-            pl.BlockSpec((1, cap_e), lane),
-            pl.BlockSpec((1, cap_e), lane),
-            pl.BlockSpec((1, bt, 3), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, cap_e), lane),
-                   pl.BlockSpec((1, cap_e), lane)],
-        out_shape=[jax.ShapeDtypeStruct((B, cap_e), jnp.int32),
-                   jax.ShapeDtypeStruct((B, cap_e), jnp.int32)],
-        scratch_shapes=[_SCRATCH((1, cap_e))],
+        in_specs=[row, row, row,
+                  pl.BlockSpec((1, bt, 3), lambda i, j: (i, j, 0))],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, cap_e), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, cap_e), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, cap_e), jnp.float32)],
         interpret=interpret,
-    )(sup, alive, rm, tris)
+    )(sup[:, None], alive[:, None], rm[:, None], tris)
+    return sup_out[:, 0], alive_out[:, 0]
 
 
 def feasible_tiles(cap_e: int, cap_t: int,
@@ -167,8 +172,10 @@ def autotune_tiles(cap_e: int, cap_t: int, *,
                    seed: int = 0) -> int:
     """Pick the fastest feasible ``bt`` by timing one fused round per
     candidate on synthetic data; cached per (shape, backend) like the
-    ``triangle_count`` tuner.  Falls back to the largest divisor tile when
-    nothing is feasible under the budget."""
+    ``triangle_count`` tuner.  Raises ``ValueError`` when no candidate
+    divides ``cap_t`` within the VMEM budget; a candidate that fails to
+    compile or run raises too — a feasible tile the chip refuses is a bug
+    in ``kernel_vmem_bytes``, not a tile to skip."""
     cands = tuple(candidates or DEFAULT_TILE_CANDIDATES)
     key = (cap_e, cap_t, jax.default_backend(), bool(interpret), cands,
            budget_bytes)
@@ -176,10 +183,9 @@ def autotune_tiles(cap_e: int, cap_t: int, *,
         return _TUNE_CACHE[key]
     feas = feasible_tiles(cap_e, cap_t, cands, budget_bytes)
     if not feas:
-        bt = next((b for b in (128, 64, 32, 16, 8, 4, 2, 1)
-                   if cap_t % b == 0), 1)
-        _TUNE_CACHE[key] = bt
-        return bt
+        raise ValueError(
+            f"no tile in {cands} divides cap_t={cap_t} within the "
+            f"{budget_bytes}-byte VMEM budget at cap_e={cap_e}")
     rng = np.random.default_rng(seed)
     sup = jnp.asarray(rng.integers(0, 8, (1, cap_e)), jnp.int32)
     alive = jnp.ones((1, cap_e), jnp.int32)
@@ -188,10 +194,7 @@ def autotune_tiles(cap_e: int, cap_t: int, *,
     best, best_t = feas[0], float("inf")
     for bt in feas:
         fn = functools.partial(fused_round, bt=bt, interpret=interpret)
-        try:
-            jax.block_until_ready(fn(sup, alive, rm, tris))  # warm up
-        except Exception:
-            continue
+        jax.block_until_ready(fn(sup, alive, rm, tris))  # warm up
         t0 = time.perf_counter()
         for _ in range(repeats):
             jax.block_until_ready(fn(sup, alive, rm, tris))

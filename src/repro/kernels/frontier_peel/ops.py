@@ -47,8 +47,8 @@ def resolve_kernel(kernel: str, cap_e: int, n_tris: int, *,
                    backend: str | None = None) -> str:
     """Resolve a ``kernel="pallas"|"xla"|"auto"`` knob to a concrete engine.
 
-    "auto" picks Pallas only when (a) the backend is TPU — jax 0.4.37 has no
-    CPU Pallas lowering, so off-TPU auto always takes the XLA oracle, the
+    "auto" picks Pallas only when (a) the backend is TPU — Pallas has no
+    CPU lowering, so off-TPU auto always takes the XLA oracle, the
     ``edge_support_auto`` precedent; (b) some tile fits the VMEM budget for
     this cap_e; and (c) the lane is triangle-dense (3T >= E), where the
     dense sweep's MXU work beats the sparse gather chain.
@@ -71,13 +71,20 @@ def resolve_kernel(kernel: str, cap_e: int, n_tris: int, *,
 def resolve_tile(cap_e: int, n_tris: int, bt, interpret: bool) -> int:
     """Concrete tile size: explicit int passes through; "auto" takes the
     largest budget-feasible candidate no bigger than the (pow2-rounded)
-    triangle count — divisibility is handled by padding, not rejection."""
+    triangle count — divisibility is handled by padding, not rejection.
+
+    Raises ``ValueError`` when no candidate fits the VMEM budget at this
+    ``cap_e``: "auto" routing never gets here then (``resolve_kernel``
+    checks the same fit), so the lane was forced onto the kernel with
+    ``kernel="pallas"`` and would overflow VMEM on the chip."""
     if bt != "auto":
         return int(bt)
     fits = [c for c in fk.DEFAULT_TILE_CANDIDATES
             if fk.kernel_vmem_bytes(cap_e, c) <= fk.VMEM_BUDGET_BYTES]
     if not fits:
-        return 128
+        raise ValueError(
+            f"no fused-peel tile fits the {fk.VMEM_BUDGET_BYTES}-byte VMEM "
+            f"budget at cap_e={cap_e}; use kernel='xla' or 'auto'")
     cover = 1
     while cover < max(1, n_tris):
         cover *= 2

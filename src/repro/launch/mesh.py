@@ -13,11 +13,13 @@ from __future__ import annotations
 
 import jax
 
+_AUTO = jax.sharding.AxisType.Auto
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(n: int | None = None, axes=("data", "model")):
@@ -28,9 +30,10 @@ def make_host_mesh(n: int | None = None, axes=("data", "model")):
     total = len(jax.devices()) if n is None else n
     nd = total
     if len(axes) == 1:
-        return jax.make_mesh((nd,), axes)
+        return jax.make_mesh((nd,), axes, axis_types=(_AUTO,))
     d = 1
     while nd % 2 == 0 and d * d < nd:   # largest power-of-two split
         d *= 2
         nd //= 2
-    return jax.make_mesh((d, total // d), axes)
+    return jax.make_mesh((d, total // d), axes,
+                         axis_types=(_AUTO,) * 2)

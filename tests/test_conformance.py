@@ -69,6 +69,10 @@ def _check_ooc_stats(stats: OocStats, mesh, tag):
     assert stats.devices == expected_dev, tag
     if mesh is None:
         assert stats.sharded_rounds == 0, tag
+        assert stats.lane_shards == 0, tag
+    elif stats.lane_shards:
+        # a mesh-split bucket's lanes span exactly the lane ("data") axis
+        assert stats.lane_shards == mesh.shape["data"], tag
 
 
 @pytest.mark.parametrize("engine", ENGINES)

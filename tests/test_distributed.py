@@ -152,7 +152,8 @@ def test_dryrun_single_cell_small_mesh():
         import jax
         from repro.configs import registry
         from repro.launch.dryrun import run_cell
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cell = registry.get_cell("gat-cora", "full_graph_sm")
         rec = run_cell(cell, mesh, "4x2")
         assert rec["ok"], rec

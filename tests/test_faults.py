@@ -72,6 +72,28 @@ def test_oom_is_retryable_injected_is_not():
     assert not faults.is_retryable(RuntimeError("shape mismatch"))
 
 
+def test_real_jax_runtime_error_classification():
+    """The runtime's own error type, raised by a real failing dispatch, is
+    what the retry ladder sees on the chip: only its OOM message retries."""
+    import jax
+    import jax.numpy as jnp
+
+    def failing(text):
+        def cb(x):
+            raise MemoryError(text)
+
+        fn = jax.jit(lambda x: jax.pure_callback(
+            cb, jax.ShapeDtypeStruct((3,), jnp.float32), x))
+        with pytest.raises(jax.errors.JaxRuntimeError) as info:
+            jax.block_until_ready(fn(jnp.ones(3, jnp.float32)))
+        return info.value
+
+    assert isinstance(faults.make_oom("dispatch", {}),
+                      jax.errors.JaxRuntimeError)
+    assert faults.is_retryable(failing("RESOURCE_EXHAUSTED: vmem"))
+    assert not faults.is_retryable(failing("shape mismatch"))
+
+
 def test_no_plan_is_noop_and_scoped():
     faults.check(faults.DISPATCH, stage=1)             # no plan: no-op
     plan = faults.FaultPlan([faults.FaultRule(site=faults.DISPATCH,

@@ -155,7 +155,7 @@ def test_resolve_kernel_routing():
     assert ops.resolve_kernel("pallas", 1 << 30, 0) == "pallas"
     with pytest.raises(ValueError):
         ops.resolve_kernel("mxu", 64, 64)
-    # auto: never Pallas off-TPU (jax 0.4.37 has no CPU lowering)
+    # auto: never Pallas off-TPU (Pallas has no CPU lowering)
     assert ops.resolve_kernel("auto", 64, 10_000, backend="cpu") == "xla"
     # auto on TPU: dense lanes route to the kernel, sparse lanes and
     # VMEM-overflowing caps fall back
@@ -185,3 +185,25 @@ def test_autotune_tiles_returns_feasible():
     assert fk.kernel_vmem_bytes(128, bt) <= fk.VMEM_BUDGET_BYTES
     # cached: same key returns the same tile without re-timing
     assert fk.autotune_tiles(128, 512, interpret=True) == bt
+
+
+def test_resolve_tile_raises_when_no_tile_fits():
+    # "auto" never routes such a lane to the kernel; forcing it must fail
+    # here rather than overflow VMEM on the chip
+    huge = fk.VMEM_BUDGET_BYTES
+    assert ops.resolve_kernel("auto", huge, 10 * huge, backend="tpu") == "xla"
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.resolve_tile(huge, 1024, "auto", False)
+    assert ops.resolve_tile(huge, 1024, 128, False) == 128   # explicit wins
+
+
+def test_autotune_tiles_reraises(monkeypatch):
+    with pytest.raises(ValueError, match="VMEM"):
+        fk.autotune_tiles(fk.VMEM_BUDGET_BYTES, 1024, interpret=True)
+
+    def refused(*args, **kwargs):
+        raise RuntimeError("Mosaic refused the tile")
+
+    monkeypatch.setattr(fk, "fused_round", refused)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        fk.autotune_tiles(96, 256, interpret=True)

@@ -71,6 +71,16 @@ class TestTriangleCount:
             assert 512 % bm == 0 and 512 % bn == 0 and 512 % bk == 0
             assert kernel_vmem_bytes(bm, bn, bk) <= VMEM_BUDGET_BYTES
 
+    def test_autotune_reraises_compile_failure(self, monkeypatch):
+        from repro.kernels.triangle_count import kernel as tk
+
+        def refused(*args, **kwargs):
+            raise RuntimeError("Mosaic refused the tile")
+
+        monkeypatch.setattr(tk, "triangle_count_kernel", refused)
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            tk.autotune_tiles(96, interpret=True, repeats=1)
+
     def test_autotune_smoke(self, rng):
         from repro.kernels.triangle_count.kernel import autotune_tiles
         from repro.kernels.triangle_count.ops import (adjacency_from_edges,

@@ -191,6 +191,9 @@ def test_sharded_rounds_8_devices():
             assert (res_s.phi == res_1.phi).all()
             assert res_s.stats.sharded_rounds > 0
             assert res_s.stats.devices == 8
+            # the first sharded bucket's output holds 8 distinct lane slices
+            assert res_s.stats.lane_shards == 8
+            assert res_1.stats.lane_shards == 0
             td = top_down_decompose(n, ce, budget=budget, mesh=mesh)
             assert (td.phi == oracle).all()
             assert td.stats.sharded_rounds > 0
@@ -210,8 +213,18 @@ def test_sharded_rounds_8_devices():
                 bucket.alive)
             phi_s, _ = h.result()
             assert h.sharded
+            assert h.lane_split[0] == 8
+            assert h.lane_split[0] * h.lane_split[1] == -(-bucket.n_lanes
+                                                          // 8) * 8
             assert phi_s.shape == phi_1.shape
             assert (phi_s == phi_1).all()
+        # a (data, tri) 4x2 mesh splits lanes over "data" only: 4 lane
+        # slices, each held by the 2 devices of its "tri" row
+        mesh2 = jax.make_mesh((4, 2), ("data", "tri"))
+        res_2 = bottom_up_decompose(n, ce, budget, mesh=mesh2,
+                                    mesh_axis=("data", "tri"))
+        assert (res_2.phi == oracle).all()
+        assert res_2.stats.lane_shards == 4
         print("SHARDED-OOC-OK")
     """)
     assert "SHARDED-OOC-OK" in out
