@@ -69,6 +69,7 @@ from repro.core import partition as plib
 from repro.core.store import GraphStore
 from repro.core.peel import (local_threshold_peel, peel_classes,
                              peel_classes_batched, peel_threshold)
+from repro.core.spans import span
 from repro.core import support as sup_lib
 from repro.core.support import (list_triangles, list_triangles_np,
                                 support_from_triangle_list)
@@ -302,6 +303,8 @@ class OocStats:
     #                           first bucket split over a mesh (its output
     #                           sharding; 1 = every device held every lane)
     lanes_per_shard: int = 0  # lane rows each device held in that bucket
+    h2d_bytes: int = 0        # graph bytes the dispatches copied host to
+    #                           device (their truss.upload spans)
 
     @property
     def prefetch_hit_rate(self) -> float:
@@ -356,7 +359,9 @@ class OocStats:
     def count_lanes(self, handle) -> None:
         """Credit one dispatch's lanes to the engine it took
         (``PendingPeel.engine``); host short-circuits count as neither.
-        The first mesh-split bucket also records its lane split."""
+        The first mesh-split bucket also records its lane split.  The
+        dispatch's uploaded bytes go to ``h2d_bytes``."""
+        self.h2d_bytes += handle.h2d_bytes
         if handle.engine == "pallas":
             self.pallas_lanes += handle.lanes
             self.pallas_max_lanes = max(self.pallas_max_lanes, handle.lanes)
@@ -691,95 +696,102 @@ def _partition_rounds(
     ladder: list = []
     while g.m:
         stats.rounds += 1
-        # the host-side "between rounds" fault site: the natural place for
-        # the crash/kill injections the resume tests drive (DESIGN.md §12)
-        faults.check(faults.PARTITIONER, stage=1, round=stats.rounds,
-                     budget=cur_budget)
-        parts = part_fn(g, cur_budget, stats.rounds)
-        if not parts:
-            break
-        spilled_round = tris_cur is None and tris_key is not None
-        if spilled_round:
-            # chunk-stream the spilled list through the batch builder
-            # (DESIGN.md §16): the builder retains only the rows assigned
-            # into some part, so the host's peak triangle working set is
-            # the round's bucket payload plus one store chunk — never the
-            # whole 3·T list the old whole-array reload materialized
-            stats.tri_rescans_avoided += 1
-            tris_in = sup_lib.iter_triangle_chunks(store, tris_key)
-        elif tris_cur is None:
-            tris_cur = np.asarray(list_triangles(g), np.int64).reshape(-1, 3)
-            tris_in = tris_cur
-        else:
-            stats.tri_rescans_avoided += 1
-            tris_in = tris_cur
-        batch = plib.build_partition_batch(
-            g, parts, with_incidence=with_incidence,
-            lane_multiple=lane_multiple, tris=tris_in,
-            shape_ladder=ladder if lane_multiple > 1 else None)
-        if spilled_round:
-            stats.tri_reload_peak_rows = max(stats.tri_reload_peak_rows,
-                                             batch.tri_peak_rows)
-        if lane_multiple > 1:
-            for b in batch.buckets:
-                shape = (b.cap_e, b.cap_t, b.n_lanes)
-                if shape not in ladder:
-                    ladder.append(shape)
-        stats.absorb_batch(batch)
-        observe = getattr(part_fn, "observe", None)
-        if observe is not None:
-            observe(batch)     # adaptive zone sizing feedback (§11)
-        removed = np.zeros(g.m, dtype=bool)
-        for bucket in batch.buckets:
-            removed[bucket.edge_ids[bucket.internal]] = True
-        if not removed.any():
-            # the batch is discarded un-launched; keep ``batches`` meaning
-            # "device launches"
-            stats.batches -= len(batch.buckets)
-            cur_budget *= 2
-            continue
-        ids_snapshot = cur_ids
-        cur_ids = cur_ids[~removed]
-        g_prev, g = g, g.remove_edges(removed)
-        remap = np.cumsum(~removed) - 1          # old id -> compacted id
-        if tris_cur is not None and len(tris_cur):
-            keep = ~removed[tris_cur].any(axis=1)
-            tris_cur = remap[tris_cur[keep]]
-        if store is not None:
-            # spill the successor BEFORE releasing the predecessor: the
-            # chunk-wise filter aliases untouched chunk files, and the
-            # refcounts must see them registered before the old graph's
-            # release decrements them
-            g.spill()
-            g_prev.release()
+        # the round's host work; the span closes before the yield, so
+        # the consumer's device work between rounds is not counted in it
+        with span("round_build", round=stats.rounds) as sp:
+            # the host-side "between rounds" fault site: the natural place for
+            # the crash/kill injections the resume tests drive (DESIGN.md §12)
+            faults.check(faults.PARTITIONER, stage=1, round=stats.rounds,
+                         budget=cur_budget)
+            parts = part_fn(g, cur_budget, stats.rounds)
+            if not parts:
+                break
+            spilled_round = tris_cur is None and tris_key is not None
             if spilled_round:
-                # stream-filter the old spilled list into a fresh key: one
-                # chunk resident at a time, and the writer must not clobber
-                # the key it is still reading from, so the key alternates
-                # per round and the predecessor is released after close
-                new_key = store.graph_key() + "/tris"
-                with sup_lib.stream_spill_triangles(store, new_key) as w:
-                    for chunk in sup_lib.iter_triangle_chunks(store,
-                                                              tris_key):
-                        stats.tri_reload_peak_rows = max(
-                            stats.tri_reload_peak_rows, int(len(chunk)))
-                        keep = ~removed[chunk].any(axis=1)
-                        w.append(remap[chunk[keep]])
-                    spilled_rows = w.rows
-                if new_key != tris_key:
-                    store.release(tris_key)
-                tris_key = new_key
+                # chunk-stream the spilled list through the batch builder
+                # (DESIGN.md §16): the builder retains only the rows assigned
+                # into some part, so the host's peak triangle working set is
+                # the round's bucket payload plus one store chunk — never the
+                # whole 3·T list the old whole-array reload materialized
+                stats.tri_rescans_avoided += 1
+                tris_in = sup_lib.iter_triangle_chunks(store, tris_key)
+            elif tris_cur is None:
+                tris_cur = np.asarray(list_triangles(g), np.int64).reshape(-1, 3)
+                tris_in = tris_cur
             else:
-                if tris_key is None:
-                    tris_key = store.graph_key() + "/tris"
-                sup_lib.spill_triangles(store, tris_key, tris_cur)
-                spilled_rows = len(tris_cur)
-            stats.tri_spill_rows = max(stats.tri_spill_rows,
-                                       int(spilled_rows))
-            tris_cur = None
-            # warm the next round's reads while the consumer peels this one
-            g.prefetch()
-            store.prefetch([tris_key])
+                stats.tri_rescans_avoided += 1
+                tris_in = tris_cur
+            batch = plib.build_partition_batch(
+                g, parts, with_incidence=with_incidence,
+                lane_multiple=lane_multiple, tris=tris_in,
+                shape_ladder=ladder if lane_multiple > 1 else None)
+            sp.count(parts=batch.n_parts,
+                     lanes=sum(b.n_lanes for b in batch.buckets),
+                     padded_slots=batch.padded_slots,
+                     real_edges=batch.real_edges)
+            if spilled_round:
+                stats.tri_reload_peak_rows = max(stats.tri_reload_peak_rows,
+                                                 batch.tri_peak_rows)
+            if lane_multiple > 1:
+                for b in batch.buckets:
+                    shape = (b.cap_e, b.cap_t, b.n_lanes)
+                    if shape not in ladder:
+                        ladder.append(shape)
+            stats.absorb_batch(batch)
+            observe = getattr(part_fn, "observe", None)
+            if observe is not None:
+                observe(batch)     # adaptive zone sizing feedback (§11)
+            removed = np.zeros(g.m, dtype=bool)
+            for bucket in batch.buckets:
+                removed[bucket.edge_ids[bucket.internal]] = True
+            if not removed.any():
+                # the batch is discarded un-launched; keep ``batches`` meaning
+                # "device launches"
+                stats.batches -= len(batch.buckets)
+                cur_budget *= 2
+                continue
+            ids_snapshot = cur_ids
+            cur_ids = cur_ids[~removed]
+            g_prev, g = g, g.remove_edges(removed)
+            remap = np.cumsum(~removed) - 1          # old id -> compacted id
+            if tris_cur is not None and len(tris_cur):
+                keep = ~removed[tris_cur].any(axis=1)
+                tris_cur = remap[tris_cur[keep]]
+            if store is not None:
+                # spill the successor BEFORE releasing the predecessor: the
+                # chunk-wise filter aliases untouched chunk files, and the
+                # refcounts must see them registered before the old graph's
+                # release decrements them
+                g.spill()
+                g_prev.release()
+                if spilled_round:
+                    # stream-filter the old spilled list into a fresh key: one
+                    # chunk resident at a time, and the writer must not clobber
+                    # the key it is still reading from, so the key alternates
+                    # per round and the predecessor is released after close
+                    new_key = store.graph_key() + "/tris"
+                    with sup_lib.stream_spill_triangles(store, new_key) as w:
+                        for chunk in sup_lib.iter_triangle_chunks(store,
+                                                                  tris_key):
+                            stats.tri_reload_peak_rows = max(
+                                stats.tri_reload_peak_rows, int(len(chunk)))
+                            keep = ~removed[chunk].any(axis=1)
+                            w.append(remap[chunk[keep]])
+                        spilled_rows = w.rows
+                    if new_key != tris_key:
+                        store.release(tris_key)
+                    tris_key = new_key
+                else:
+                    if tris_key is None:
+                        tris_key = store.graph_key() + "/tris"
+                    sup_lib.spill_triangles(store, tris_key, tris_cur)
+                    spilled_rows = len(tris_cur)
+                stats.tri_spill_rows = max(stats.tri_spill_rows,
+                                           int(spilled_rows))
+                tris_cur = None
+                # warm the next round's reads while the consumer peels this one
+                g.prefetch()
+                store.prefetch([tris_key])
         # zone state as of THIS round's observe — the value the next
         # round's planning reads, hence the one a resume from this round's
         # snapshot must restore.  Captured here because the double-buffered
@@ -817,10 +829,12 @@ def _retry_stage1_round(eng: _Engine, stats: OocStats, shape_cache,
     that failed halfway through folding simply re-folds everything.
     """
     split = 1
+    attempt = 0
     while True:
         if not faults.is_retryable(exc):
             raise exc
         stats.retries += 1
+        attempt += 1
         if split < (1 << max_retries):
             split *= 2
         elif eng.mesh is not None:
@@ -832,26 +846,28 @@ def _retry_stage1_round(eng: _Engine, stats: OocStats, shape_cache,
             stats.degraded += 1
             raise _RestartRounds(max(cur_budget // 2, _MIN_ROUND_BUDGET))
         try:
-            for bi, bucket in enumerate(batch.buckets):
-                for si, sub in enumerate(
-                        plib.split_bucket_lanes(bucket, split)):
-                    # a sub-bucket whose lane count no longer divides the
-                    # mesh axis runs single-device (the point is a smaller
-                    # footprint, not preserving the routing)
-                    mesh = (eng.mesh if eng.mesh is not None
-                            and sub.n_lanes % eng.n_dev == 0 else None)
-                    h = peel_classes_batched(
-                        sub.sup, sub.tris, sub.indptr, sub.tids, sub.alive,
-                        shape_cache=shape_cache, blocking=False,
-                        mesh=mesh, mesh_axis=eng.mesh_axis,
-                        kernel=eng.kernel,
-                        fault_ctx={"stage": 1, "round": round_idx,
-                                   "bucket": bi, "sub": si, "retry": split})
-                    stats.compiles += int(h.new_compile)
-                    stats.count_lanes(h)
-                    stats.batches += 1
-                    phi_b, _ = h.result()
-                    fold_bucket(round_idx, sub, ids, np.asarray(phi_b))
+            with span("retry", stage="lb", attempt=attempt):
+                for bi, bucket in enumerate(batch.buckets):
+                    for si, sub in enumerate(
+                            plib.split_bucket_lanes(bucket, split)):
+                        # a sub-bucket whose lane count no longer divides
+                        # the mesh axis runs single-device (the point is a
+                        # smaller footprint, not preserving the routing)
+                        mesh = (eng.mesh if eng.mesh is not None
+                                and sub.n_lanes % eng.n_dev == 0 else None)
+                        h = peel_classes_batched(
+                            sub.sup, sub.tris, sub.indptr, sub.tids,
+                            sub.alive, shape_cache=shape_cache,
+                            blocking=False, mesh=mesh,
+                            mesh_axis=eng.mesh_axis, kernel=eng.kernel,
+                            fault_ctx={"stage": 1, "round": round_idx,
+                                       "bucket": bi, "sub": si,
+                                       "retry": split})
+                        stats.compiles += int(h.new_compile)
+                        stats.count_lanes(h)
+                        stats.batches += 1
+                        phi_b, _ = h.result()
+                        fold_bucket(round_idx, sub, ids, np.asarray(phi_b))
             return
         except Exception as e:
             exc = e
@@ -1063,7 +1079,7 @@ class BottomUpResult:
 
 
 def _retry_candidate_peel(eng: _Engine, stats: OocStats, exc, dispatch,
-                          max_retries: int = 2):
+                          max_retries: int = 2, *, stage: str):
     """Blocking retry ladder for a failed stage-2 / top-down candidate peel
     (DESIGN.md §12).  The candidate's host arrays survive the donation, so
     a retry is a plain re-dispatch of the same level (``dispatch(retry,
@@ -1071,7 +1087,8 @@ def _retry_candidate_peel(eng: _Engine, stats: OocStats, exc, dispatch,
     ``max_retries`` failures the mesh is dropped — single-device is the
     memory floor for a candidate peel, whose size is set by the k-class
     structure rather than the round budget — and the retry budget resets
-    once on the degraded engine; then the failure propagates.
+    once on the degraded engine; then the failure propagates.  ``stage``
+    names the ladder's ``truss.retry`` spans (``"s2"`` or ``"td"``).
     """
     attempt = 0
     while True:
@@ -1086,7 +1103,8 @@ def _retry_candidate_peel(eng: _Engine, stats: OocStats, exc, dispatch,
             stats.degraded += 1
             attempt = 0
         try:
-            return dispatch(attempt, eng)
+            with span("retry", stage=stage, attempt=attempt):
+                return dispatch(attempt, eng)
         except Exception as e:
             exc = e
 
@@ -1231,13 +1249,15 @@ def bottom_up_decompose(
         remaining edge admits class k_b (the consumer re-checks after the
         pending removal lands and jumps k past empty classes).
         """
-        masks = candidate_masks(k_b)
-        if masks is None:
-            return None
-        h_ids, internal = masks
-        local_edges, verts = glib.compact_edge_list(edges[h_ids])
-        sub = glib.build_graph(len(verts), local_edges)
-        tris = np.asarray(list_triangles(sub), np.int32).reshape(-1, 3)
+        with span("candidate_build", k=int(k_b)) as sp:
+            masks = candidate_masks(k_b)
+            if masks is None:
+                return None
+            h_ids, internal = masks
+            sp.count(edges=len(h_ids))
+            local_edges, verts = glib.compact_edge_list(edges[h_ids])
+            sub = glib.build_graph(len(verts), local_edges)
+            tris = np.asarray(list_triangles(sub), np.int32).reshape(-1, 3)
         return k_b, h_ids, tris, internal
 
     k = k0
@@ -1321,7 +1341,7 @@ def bottom_up_decompose(
                     return rem
 
                 removed = _retry_candidate_peel(eng, stats, exc, redispatch,
-                                                max_retries)
+                                                max_retries, stage="s2")
         rm_glob = h_ids[removed]
         phi[rm_glob] = k
         remaining[rm_glob] = False
@@ -1399,10 +1419,12 @@ def _retry_support_round(eng: _Engine, stats: OocStats, round_idx: int,
     once, after the whole round has been recomputed successfully.
     """
     split = 1
+    attempt = 0
     while True:
         if not faults.is_retryable(exc):
             raise exc
         stats.retries += 1
+        attempt += 1
         if split < (1 << max_retries):
             split *= 2
         elif eng.mesh is not None:
@@ -1414,14 +1436,11 @@ def _retry_support_round(eng: _Engine, stats: OocStats, round_idx: int,
             stats.degraded += 1
             raise _RestartRounds(max(cur_budget // 2, _MIN_ROUND_BUDGET))
         try:
-            trips = []
-            for bi, bucket in enumerate(batch.buckets):
-                for si, sub in enumerate(
-                        plib.split_bucket_lanes(bucket, split)):
-                    trips.append(
-                        _support_credit_triples(sub, round_idx, bi, si,
-                                                split))
-            return trips
+            with span("retry", stage="sup", attempt=attempt):
+                return [_support_credit_triples(sub, round_idx, bi, si, split)
+                        for bi, bucket in enumerate(batch.buckets)
+                        for si, sub in enumerate(
+                            plib.split_bucket_lanes(bucket, split))]
         except Exception as e:
             exc = e
 
@@ -1551,22 +1570,26 @@ def partitioned_support(
             for round_idx, batch, ids, cur_b, zs in _partition_rounds(
                     n, edges, cur_budget, part_fn, stats,
                     with_incidence=False, start_ids=start_ids, store=store):
-                try:
-                    trips = [
-                        _support_credit_triples(bucket, round_idx, bi, 0, 0)
-                        for bi, bucket in enumerate(batch.buckets)]
-                except Exception as exc:
-                    trips = _retry_support_round(eng, stats, round_idx,
-                                                 batch, exc, cur_b,
-                                                 max_retries)
-                # fold only after EVERY bucket's triples exist: the credits
-                # are not idempotent, so a failed round must never be
-                # partially folded (the ladder recomputes it whole)
-                for trip in trips:
-                    if len(trip):
-                        np.add.at(sup, ids[trip], 1)
-                for bucket in batch.buckets:
-                    alive[ids[bucket.edge_ids[bucket.internal]]] = False
+                with span("support_credit") as sp:
+                    try:
+                        trips = [
+                            _support_credit_triples(bucket, round_idx, bi,
+                                                    0, 0)
+                            for bi, bucket in enumerate(batch.buckets)]
+                    except Exception as exc:
+                        trips = _retry_support_round(eng, stats, round_idx,
+                                                     batch, exc, cur_b,
+                                                     max_retries)
+                    # fold only after EVERY bucket's triples exist: the
+                    # credits are not idempotent, so a failed round must
+                    # never be partially folded (the ladder recomputes it
+                    # whole)
+                    for trip in trips:
+                        if len(trip):
+                            np.add.at(sup, ids[trip], 1)
+                    for bucket in batch.buckets:
+                        alive[ids[bucket.edge_ids[bucket.internal]]] = False
+                    sp.count(triangles=sum(len(t) for t in trips) // 3)
                 if journal is not None:
                     journal.record("sup", round_idx,
                                    {"sup": sup, "alive": alive}, stats,

@@ -23,6 +23,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
+
 Int = np.int32
 
 
@@ -405,6 +407,13 @@ def build_graph(n: int, edges: np.ndarray, store=None) -> Graph:
     ``store`` attaches a :class:`~repro.core.store.GraphStore`; the graph
     stays fully resident until its first :meth:`Graph.spill`.
     """
+    with span("build_graph") as sp:
+        g = _build_graph(n, edges, store)
+        sp.count(m=g.m)
+    return g
+
+
+def _build_graph(n: int, edges: np.ndarray, store) -> Graph:
     edges = canonical_edges(edges, n)
     m = len(edges)
     deg = degrees(n, edges)

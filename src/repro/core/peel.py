@@ -52,6 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import faults
+from repro.core import spans
+from repro.core.spans import span, upload
 from repro.core.support import (_pow2_ceil, _pow4_ceil, list_triangles_np,
                                 support_from_triangle_list,
                                 triangle_incidence_np)
@@ -86,13 +88,14 @@ class PeelStats:
     cap_f: int           # frontier buffer capacity used
     cap_t: int           # triangle gather capacity used
     resumes: int         # host capacity-doubling fallbacks taken
+    h2d_bytes: int = 0   # graph bytes copied host to device (truss.upload)
 
     @classmethod
-    def from_vec(cls, vec, cap_f, cap_t, resumes):
+    def from_vec(cls, vec, cap_f, cap_t, resumes, h2d_bytes=0):
         vec = np.asarray(vec)
         return cls(int(vec[_S_ROUNDS]), int(vec[_S_REMOVED]),
                    int(vec[_S_GATHERED]), int(vec[_S_MAXF]),
-                   cap_f, cap_t, resumes)
+                   cap_f, cap_t, resumes, h2d_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -323,34 +326,38 @@ def peel_classes(sup0, tris, edge_alive0, max_k=None, *, incidence=None,
     """
     m = int(sup0.shape[0])
     if _pick_engine(engine, tris, m, with_stats) == "dense":
-        phi, alive = peel_classes_dense(
-            jnp.asarray(sup0), jnp.asarray(tris), jnp.asarray(edge_alive0),
-            max_k=max_k)
+        (sup_j, tris_j, alive_j), _ = upload(sup0, tris, edge_alive0)
+        phi, alive = peel_classes_dense(sup_j, tris_j, alive_j, max_k=max_k)
         # the dense baseline has no frontier counters (explicit engine="dense")
         return (phi, alive, None) if with_stats else (phi, alive)
     indptr, tri_ids = _prep_incidence(tris, m, incidence)
     cap_f, cap_t = _default_caps(m, (indptr, tri_ids), cap_f, cap_t)
-    tris_j = jnp.asarray(tris)
-    indptr_j = jnp.asarray(indptr)
-    tids_j = jnp.asarray(tri_ids)
-    alive = jnp.asarray(edge_alive0)
-    sup = jnp.asarray(sup0)
+    (tris_j, indptr_j, tids_j, alive, sup), h2d = upload(
+        tris, indptr, tri_ids, edge_alive0, sup0)
     phi = jnp.zeros(m, jnp.int32)
     k = jnp.int32(2)
     stats = jnp.zeros(N_STATS, jnp.int32)
     resumes = 0
     while True:
-        # trusscheck: allow[TRK104] -- loop-carried arrays keep their (m,)/(T,3) shapes; only cap_t changes, and that retrace IS the deliberate capacity-resume (at most log2 resumes)
-        alive, sup, phi, k, stats, overflow = peel_classes_fixedcap(
-            sup, tris_j, indptr_j, tids_j, alive, phi, k, stats,
-            cap_f=cap_f, cap_t=cap_t, max_k=max_k)
-        # trusscheck: allow[TRK105] -- capacity-resume: the host must read the overflow flag to decide the recompile-at-2x resume (one sync per resume, not per round)
-        if not bool(overflow):
+        with span("dispatch", engine="frontier", lanes=1) as sp:
+            compiled = peel_classes_fixedcap._cache_size()
+            # trusscheck: allow[TRK104] -- loop-carried arrays keep their (m,)/(T,3) shapes; only cap_t changes, and that retrace IS the deliberate capacity-resume (at most log2 resumes)
+            alive, sup, phi, k, stats, overflow = peel_classes_fixedcap(
+                sup, tris_j, indptr_j, tids_j, alive, phi, k, stats,
+                cap_f=cap_f, cap_t=cap_t, max_k=max_k)
+            sp.count(new_compile=peel_classes_fixedcap._cache_size()
+                     > compiled)
+        # the wait includes the flag's copy back to the host
+        with span("device_wait", resumes=resumes):
+            # trusscheck: allow[TRK105] -- capacity-resume: the host must read the overflow flag to decide the recompile-at-2x resume (one sync per resume, not per round)
+            done = not bool(overflow)
+        if done:
             break
         cap_t *= 2          # host fallback: double and resume
         resumes += 1
     if with_stats:
-        return phi, alive, PeelStats.from_vec(stats, cap_f, cap_t, resumes)
+        return phi, alive, PeelStats.from_vec(stats, cap_f, cap_t, resumes,
+                                              h2d)
     return phi, alive
 
 
@@ -370,36 +377,37 @@ def peel_threshold(sup0, tris, alive0, removable, thresh, *, incidence=None,
     """
     m = int(sup0.shape[0])
     if _pick_engine(engine, tris, m, with_stats) == "dense":
-        alive, sup, removed = peel_threshold_dense(
-            jnp.asarray(sup0), jnp.asarray(tris), jnp.asarray(alive0),
-            jnp.asarray(removable), jnp.int32(thresh))
+        args, _ = upload(sup0, tris, alive0, removable)
+        alive, sup, removed = peel_threshold_dense(*args, jnp.int32(thresh))
         return (alive, sup, removed, None) if with_stats else \
             (alive, sup, removed)
     indptr, tri_ids = _prep_incidence(tris, m, incidence)
     cap_f, cap_t = _default_caps(m, (indptr, tri_ids), cap_f, cap_t)
-    tris_j = jnp.asarray(tris)
-    indptr_j = jnp.asarray(indptr)
-    tids_j = jnp.asarray(tri_ids)
-    alive0 = jnp.asarray(alive0)
+    (tris_j, indptr_j, tids_j, alive0, sup, removable), h2d = upload(
+        tris, indptr, tri_ids, alive0, sup0, removable)
     alive = alive0
-    sup = jnp.asarray(sup0)
-    removable = jnp.asarray(removable)
     thresh = jnp.int32(thresh)
     stats = jnp.zeros(N_STATS, jnp.int32)
     resumes = 0
     while True:
-        # trusscheck: allow[TRK104] -- loop-carried arrays keep their (m,)/(T,3) shapes; only cap_t changes, and that retrace IS the deliberate capacity-resume (at most log2 resumes)
-        alive, sup, stats, overflow = peel_threshold_fixedcap(
-            sup, tris_j, indptr_j, tids_j, alive, removable, thresh, stats,
-            cap_f=cap_f, cap_t=cap_t)
-        # trusscheck: allow[TRK105] -- capacity-resume: the host must read the overflow flag to decide the recompile-at-2x resume (one sync per resume, not per round)
-        if not bool(overflow):
+        with span("dispatch", engine="frontier", lanes=1) as sp:
+            compiled = peel_threshold_fixedcap._cache_size()
+            # trusscheck: allow[TRK104] -- loop-carried arrays keep their (m,)/(T,3) shapes; only cap_t changes, and that retrace IS the deliberate capacity-resume (at most log2 resumes)
+            alive, sup, stats, overflow = peel_threshold_fixedcap(
+                sup, tris_j, indptr_j, tids_j, alive, removable, thresh,
+                stats, cap_f=cap_f, cap_t=cap_t)
+            sp.count(new_compile=peel_threshold_fixedcap._cache_size()
+                     > compiled)
+        with span("device_wait", resumes=resumes):
+            # trusscheck: allow[TRK105] -- capacity-resume: the host must read the overflow flag to decide the recompile-at-2x resume (one sync per resume, not per round)
+            done = not bool(overflow)
+        if done:
             break
         cap_t *= 2
         resumes += 1
     if with_stats:
         return alive, sup, alive0 & ~alive, PeelStats.from_vec(
-            stats, cap_f, cap_t, resumes)
+            stats, cap_f, cap_t, resumes, h2d)
     return alive, sup, alive0 & ~alive
 
 
@@ -461,18 +469,22 @@ class PendingPeel:
     ``lane_split`` is ``(lane_shards, lanes_per_shard)`` for a bucket whose
     lanes were split over a mesh, read off the output's sharding: the
     number of distinct lane slices the devices hold and the lane rows of
-    each; ``None`` for a single-device dispatch.
+    each; ``None`` for a single-device dispatch.  ``h2d_bytes`` is the
+    graph bytes the dispatch copied to the device (its ``truss.upload``
+    spans).
     """
 
     def __init__(self, finalize, new_compile: bool, sharded: bool = False,
                  fault_ctx: Optional[dict] = None, engine: str = "xla",
-                 lanes: int = 1, lane_split: Optional[tuple] = None):
+                 lanes: int = 1, lane_split: Optional[tuple] = None,
+                 h2d_bytes: int = 0):
         self._finalize = finalize
         self.new_compile = bool(new_compile)
         self.sharded = bool(sharded)
         self.engine = engine
         self.lanes = int(lanes)
         self.lane_split = lane_split
+        self.h2d_bytes = int(h2d_bytes)
         self._fault_ctx = fault_ctx
         self._out = None
         self._error = None
@@ -488,7 +500,8 @@ class PendingPeel:
             try:
                 if self._fault_ctx is not None:
                     faults.check(faults.FINALIZE, **self._fault_ctx)
-                self._out = finalize()
+                with span("device_wait"):
+                    self._out = finalize()
             except BaseException as e:
                 self._error = e
                 raise
@@ -595,10 +608,12 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         new = shape_cache is not None and key not in shape_cache
         if shape_cache is not None:
             shape_cache.add(key)
-        phi_d, st_d = peel_classes_batched_sharded(
-            mesh, np.asarray(sup_b), tris_np, np.asarray(indptr_b),
-            np.asarray(tids_b), np.asarray(alive_b),
-            cap_f=cap_f, cap_t=cap_t, axis=mesh_axis)
+        # the sharded dispatch copies its inputs itself
+        with span("dispatch", engine="mesh", lanes=B, new_compile=new):
+            phi_d, st_d = peel_classes_batched_sharded(
+                mesh, np.asarray(sup_b), tris_np, np.asarray(indptr_b),
+                np.asarray(tids_b), np.asarray(alive_b),
+                cap_f=cap_f, cap_t=cap_t, axis=mesh_axis)
 
         def _finish():
             # drop the lanes pad_bucket_lanes appended for the mesh split
@@ -612,10 +627,12 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
             return PendingPeel(_finish, new, sharded=True,
                                fault_ctx=fault_ctx, lanes=B,
                                lane_split=lane_split)
-        phi, st = _finish()
+        with span("device_wait"):
+            phi, st = _finish()
         return phi, st, new
     from repro.kernels.frontier_peel import ops as frontier_ops
 
+    lanes = int(sup_b.shape[0])
     if frontier_ops.resolve_kernel(kernel, cap_e,
                                    int(tris_np.shape[1])) == "pallas":
         interpret = jax.default_backend() != "tpu"
@@ -625,27 +642,34 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         new = shape_cache is not None and key not in shape_cache
         if shape_cache is not None:
             shape_cache.add(key)
-        phi_d, st_d = frontier_ops.peel_classes_fused(
-            np.asarray(sup_b), tris_np, np.asarray(alive_b),
-            bt=bt, interpret=interpret)
+        # the fused kernel takes int32 rows: converted on the host, as
+        # jnp.asarray(x, jnp.int32) would
+        (sup_d, tris_d, alive_d), h2d = upload(
+            np.asarray(sup_b, np.int32), np.asarray(tris_np, np.int32),
+            np.asarray(alive_b, np.int32))
+        with span("dispatch", engine="pallas", lanes=lanes,
+                  new_compile=new):
+            phi_d, st_d = frontier_ops.peel_classes_fused(
+                sup_d, tris_d, alive_d, bt=bt, interpret=interpret)
         if not blocking:
             return PendingPeel(
                 lambda: (np.asarray(phi_d), np.asarray(st_d)), new,
-                fault_ctx=fault_ctx, engine="pallas",
-                lanes=int(sup_b.shape[0]))
-        return np.asarray(phi_d), np.asarray(st_d), new
+                fault_ctx=fault_ctx, engine="pallas", lanes=lanes,
+                h2d_bytes=h2d)
+        with span("device_wait"):
+            return np.asarray(phi_d), np.asarray(st_d), new
     key = (sup_b.shape, tris_b.shape, cap_f, cap_t)
     new = shape_cache is not None and key not in shape_cache
     if shape_cache is not None:
         shape_cache.add(key)
-    phi, st = _peel_classes_vmapped(
-        jnp.asarray(sup_b), jnp.asarray(tris_b), jnp.asarray(indptr_b),
-        jnp.asarray(tids_b), jnp.asarray(alive_b),
-        cap_f=cap_f, cap_t=cap_t)
+    args, h2d = upload(sup_b, tris_b, indptr_b, tids_b, alive_b)
+    with span("dispatch", engine="xla", lanes=lanes, new_compile=new):
+        phi, st = _peel_classes_vmapped(*args, cap_f=cap_f, cap_t=cap_t)
     if not blocking:
         return PendingPeel(lambda: (np.asarray(phi), np.asarray(st)), new,
-                           fault_ctx=fault_ctx, lanes=int(sup_b.shape[0]))
-    return np.asarray(phi), np.asarray(st), new
+                           fault_ctx=fault_ctx, lanes=lanes, h2d_bytes=h2d)
+    with span("device_wait"):
+        return np.asarray(phi), np.asarray(st), new
 
 
 def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
@@ -750,10 +774,13 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
     rem_p = np.zeros(cap_e, bool)
     rem_p[:m] = removable
     if mesh is not None:
-        alive_dev, cap_f, cap_t = local_threshold_peel_sharded(
-            mesh, sup_p, tris_p, alive_p, rem_p, thresh, axis=mesh_axis)
-        key = (cap_e, cap_tri, cap_f, cap_t, ("mesh", n_dev))
-        new = shape_cache is not None and key not in shape_cache
+        # the sharded dispatch copies its inputs itself
+        with span("dispatch", engine="mesh", lanes=1) as sp:
+            alive_dev, cap_f, cap_t = local_threshold_peel_sharded(
+                mesh, sup_p, tris_p, alive_p, rem_p, thresh, axis=mesh_axis)
+            key = (cap_e, cap_tri, cap_f, cap_t, ("mesh", n_dev))
+            new = shape_cache is not None and key not in shape_cache
+            sp.count(new_compile=new)
         if shape_cache is not None:
             shape_cache.add(key)
 
@@ -764,7 +791,8 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
         if not blocking:
             return PendingPeel(_finish_sharded, new, sharded=True,
                                fault_ctx=fault_ctx)
-        alive, removed = _finish_sharded()
+        with span("device_wait"):
+            alive, removed = _finish_sharded()
         return alive, removed, new
     from repro.kernels.frontier_peel import ops as frontier_ops
 
@@ -775,9 +803,14 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
         new = shape_cache is not None and key not in shape_cache
         if shape_cache is not None:
             shape_cache.add(key)
-        alive_dev = frontier_ops.peel_threshold_fused(
-            sup_p, tris_p, rem_p, thresh, alive_p,
-            bt=bt, interpret=interpret)
+        # int32 rows, converted on the host as jnp.asarray(x, jnp.int32)
+        # would
+        (sup_d, tris_d, rem_d, alive_d), h2d = upload(
+            sup_p, tris_p, rem_p.astype(np.int32), alive_p.astype(np.int32))
+        with span("dispatch", engine="pallas", lanes=1, new_compile=new):
+            alive_dev = frontier_ops.peel_threshold_fused(
+                sup_d, tris_d, rem_d, thresh, alive_d,
+                bt=bt, interpret=interpret)
 
         def _finish_fused():
             alive = np.asarray(alive_dev)[:m] > 0
@@ -785,8 +818,9 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
 
         if not blocking:
             return PendingPeel(_finish_fused, new, fault_ctx=fault_ctx,
-                               engine="pallas")
-        alive, removed = _finish_fused()
+                               engine="pallas", h2d_bytes=h2d)
+        with span("device_wait"):
+            alive, removed = _finish_fused()
         return alive, removed, new
     indptr, tids = triangle_incidence_np(tris_p, cap_e)
     tids_p = np.zeros(3 * cap_tri, np.int32)
@@ -797,20 +831,21 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
     if shape_cache is not None:
         shape_cache.add(key)
     st0 = jnp.zeros(N_STATS, jnp.int32)
+    args, h2d = upload(sup_p, tris_p, indptr, tids_p, alive_p, rem_p)
     # _default_caps covers the largest incidence row, so overflow is
     # impossible and no resume loop is needed
-    alive_dev, _, _, _ = peel_threshold_fixedcap(
-        jnp.asarray(sup_p), jnp.asarray(tris_p), jnp.asarray(indptr),
-        jnp.asarray(tids_p), jnp.asarray(alive_p), jnp.asarray(rem_p),
-        jnp.int32(thresh), st0, cap_f=cap_f, cap_t=cap_t)
+    with span("dispatch", engine="xla", lanes=1, new_compile=new):
+        alive_dev, _, _, _ = peel_threshold_fixedcap(
+            *args, jnp.int32(thresh), st0, cap_f=cap_f, cap_t=cap_t)
 
     def _finish():
         alive = np.asarray(alive_dev)[:m]
         return alive, alive0 & ~alive
 
     if not blocking:
-        return PendingPeel(_finish, new, fault_ctx=fault_ctx)
-    alive, removed = _finish()
+        return PendingPeel(_finish, new, fault_ctx=fault_ctx, h2d_bytes=h2d)
+    with span("device_wait"):
+        alive, removed = _finish()
     return alive, removed, new
 
 
@@ -942,6 +977,7 @@ def estimate_working_set(g) -> int:
     return 4 * g.m + 6 * int((out_deg * out_deg).sum())
 
 
+@spans.job("auto")
 def truss_decompose(n: int, edges: np.ndarray, *, engine: str = "auto",
                     memory_budget=None, partitioner: str = "sequential",
                     partitioner_seed: int = 0, mesh=None,
@@ -1041,7 +1077,8 @@ def truss_decompose(n: int, edges: np.ndarray, *, engine: str = "auto",
         from repro.core.maintain import truss_maintain
 
         if phi0 is None:
-            phi0 = truss_decompose(
+            # inside this call's truss.job span
+            phi0 = truss_decompose.__wrapped__(
                 n, edges, engine=engine, memory_budget=memory_budget,
                 partitioner=partitioner, partitioner_seed=partitioner_seed,
                 mesh=mesh, mesh_axis=mesh_axis, kernel=kernel,
@@ -1093,15 +1130,14 @@ def truss_decompose(n: int, edges: np.ndarray, *, engine: str = "auto",
         else:
             from repro.core.top_down import top_down_decompose
 
-            res = top_down_decompose(n, edges, budget=part_budget,
-                                     partitioner=partitioner,
-                                     partitioner_seed=partitioner_seed,
-                                     mesh=mesh, mesh_axis=mesh_axis,
-                                     kernel=kernel,
-                                     checkpoint_dir=checkpoint_dir,
-                                     checkpoint_every=checkpoint_every,
-                                     resume=resume, max_retries=max_retries,
-                                     store=store)
+            # inside this call's truss.job span
+            res = top_down_decompose.__wrapped__(
+                n, edges, budget=part_budget, partitioner=partitioner,
+                partitioner_seed=partitioner_seed, mesh=mesh,
+                mesh_axis=mesh_axis, kernel=kernel,
+                checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every, resume=resume,
+                max_retries=max_retries, store=store)
         phi = np.asarray(res.phi).astype(np.int64)
         return (phi, res.stats) if with_stats else phi
     if checkpoint_dir is not None:
@@ -1121,9 +1157,12 @@ def truss_decompose(n: int, edges: np.ndarray, *, engine: str = "auto",
     sup = support_from_triangle_list(tris, g.m).astype(np.int32)
     if len(tris) == 0:
         tris = np.full((1, 3), g.m, np.int32)  # points at the drop slot
-    args = (jnp.asarray(sup), jnp.asarray(tris), jnp.ones(g.m, bool))
+    (sup_j, tris_j), h2d = upload(sup, tris)
+    args = (sup_j, tris_j, jnp.ones(g.m, bool))
     if with_stats:
         phi, _, stats = peel_classes(*args, engine=engine, with_stats=True)
+        if stats is not None:
+            stats.h2d_bytes += h2d
     else:
         phi, _ = peel_classes(*args, engine=engine)
         stats = None

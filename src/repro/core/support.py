@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import Graph
+from repro.core.spans import span, upload
 
 
 def _search_iters(max_row: int) -> int:
@@ -109,25 +110,28 @@ def _wedge_hits_ids_np(g: Graph, eids: np.ndarray, D: int):
 def edge_support_np(g: Graph, chunk: int = 1 << 16) -> np.ndarray:
     """Support of every canonical edge (numpy, chunked)."""
     sup = np.zeros(g.m, dtype=np.int64)
-    for e_lo in range(0, g.m, chunk):
-        e_hi = min(e_lo + chunk, g.m)
-        e_ab, e_aw, e_bw, _ = _wedge_hits_np(g, e_lo, e_hi)
-        np.add.at(sup, e_ab, 1)
-        np.add.at(sup, e_aw, 1)
-        np.add.at(sup, e_bw, 1)
+    with span("edge_support", m=g.m):
+        for e_lo in range(0, g.m, chunk):
+            e_hi = min(e_lo + chunk, g.m)
+            e_ab, e_aw, e_bw, _ = _wedge_hits_np(g, e_lo, e_hi)
+            np.add.at(sup, e_ab, 1)
+            np.add.at(sup, e_aw, 1)
+            np.add.at(sup, e_bw, 1)
     return sup
 
 
 def list_triangles_np(g: Graph, chunk: int = 1 << 16) -> np.ndarray:
     """Static triangle list: (T, 3) int32 edge-id triples, each triangle once."""
-    out = []
-    for e_lo in range(0, g.m, chunk):
-        e_hi = min(e_lo + chunk, g.m)
-        e_ab, e_aw, e_bw, _ = _wedge_hits_np(g, e_lo, e_hi)
-        out.append(np.stack([e_ab, e_aw, e_bw], axis=1))
-    if not out:
-        return np.zeros((0, 3), np.int32)
-    return np.concatenate(out, axis=0).astype(np.int32)
+    with span("list_triangles") as sp:
+        out = []
+        for e_lo in range(0, g.m, chunk):
+            e_hi = min(e_lo + chunk, g.m)
+            e_ab, e_aw, e_bw, _ = _wedge_hits_np(g, e_lo, e_hi)
+            out.append(np.stack([e_ab, e_aw, e_bw], axis=1))
+        tris = (np.concatenate(out, axis=0).astype(np.int32) if out
+                else np.zeros((0, 3), np.int32))
+        sp.count(triangles=len(tris))
+    return tris
 
 
 def list_triangles(
@@ -142,18 +146,19 @@ def list_triangles(
     ``D``, keeping the materialized wedge area at Σ_b C_b·D_b instead of
     m·D_max.  Same triangles (each exactly once), different row order.
     """
-    plan = wedge_bucket_plan(g, chunk, budget)
-    out = []
-    for bucket in plan:
-        ids = bucket.eids[: bucket.n_real].astype(np.int64)
-        for lo in range(0, len(ids), bucket.chunk):
-            e_ab, e_aw, e_bw, _ = _wedge_hits_ids_np(
-                g, ids[lo : lo + bucket.chunk], bucket.D)
-            if len(e_ab):
-                out.append(np.stack([e_ab, e_aw, e_bw], axis=1))
-    if not out:
-        return np.zeros((0, 3), np.int32)
-    return np.concatenate(out, axis=0).astype(np.int32)
+    with span("list_triangles") as sp:
+        out = []
+        for bucket in wedge_bucket_plan(g, chunk, budget):
+            ids = bucket.eids[: bucket.n_real].astype(np.int64)
+            for lo in range(0, len(ids), bucket.chunk):
+                e_ab, e_aw, e_bw, _ = _wedge_hits_ids_np(
+                    g, ids[lo : lo + bucket.chunk], bucket.D)
+                if len(e_ab):
+                    out.append(np.stack([e_ab, e_aw, e_bw], axis=1))
+        tris = (np.concatenate(out, axis=0).astype(np.int32) if out
+                else np.zeros((0, 3), np.int32))
+        sp.count(triangles=len(tris))
+    return tris
 
 
 def support_from_triangle_list(tris: np.ndarray, m: int) -> np.ndarray:
@@ -190,15 +195,18 @@ def triangle_incidence_np(tris: np.ndarray, m: int) -> tuple[np.ndarray, np.ndar
     tris = np.asarray(tris)
     if len(tris) == 0 or m == 0:
         return np.zeros(m + 1, np.int32), np.zeros(0, np.int32)
-    flat_e = tris.reshape(-1).astype(np.int64)
-    flat_t = np.repeat(np.arange(len(tris), dtype=np.int64), 3)
-    keep = flat_e < m
-    flat_e, flat_t = flat_e[keep], flat_t[keep]
-    order = np.argsort(flat_e, kind="stable")
-    tri_ids = flat_t[order].astype(np.int32)
-    indptr = np.zeros(m + 1, dtype=np.int64)
-    np.add.at(indptr, flat_e + 1, 1)
-    return np.cumsum(indptr).astype(np.int32), tri_ids
+    with span("incidence") as sp:
+        flat_e = tris.reshape(-1).astype(np.int64)
+        flat_t = np.repeat(np.arange(len(tris), dtype=np.int64), 3)
+        keep = flat_e < m
+        flat_e, flat_t = flat_e[keep], flat_t[keep]
+        order = np.argsort(flat_e, kind="stable")
+        tri_ids = flat_t[order].astype(np.int32)
+        indptr = np.zeros(m + 1, dtype=np.int64)
+        np.add.at(indptr, flat_e + 1, 1)
+        indptr = np.cumsum(indptr).astype(np.int32)
+        sp.count(slots=len(tri_ids))
+    return indptr, tri_ids
 
 
 def triangle_density(m: int, n_tris: int) -> float:
@@ -351,11 +359,10 @@ def edge_support_jax(
     """
     if g.m == 0:
         return jnp.zeros(0, jnp.int32)
-    src = jnp.asarray(np.concatenate([g.src, np.zeros(1, np.int32)]))
-    dst = jnp.asarray(np.concatenate([g.dst, np.zeros(1, np.int32)]))
-    indptr = jnp.asarray(g.indptr)
-    nbrs = jnp.asarray(g.nbrs)
-    nbr_eid = jnp.asarray(g.nbr_eid)
+    (src, dst, indptr, nbrs, nbr_eid), _ = upload(
+        np.concatenate([g.src, np.zeros(1, np.int32)]),
+        np.concatenate([g.dst, np.zeros(1, np.int32)]),
+        g.indptr, g.nbrs, g.nbr_eid)
     iters = _search_iters(g.max_out_deg)
     if bucketed:
         plan = wedge_bucket_plan(g, chunk, budget)
@@ -404,19 +411,20 @@ def edge_support_auto(
     """
     if g.m == 0:
         return np.zeros(0, np.int64)
-    verts, density = dense_core_stats(g)
-    n_act = len(verts)
-    if n_act <= dense_max_n and density >= dense_threshold:
-        from repro.kernels.triangle_count.ops import dense_edge_support
+    with span("edge_support", m=g.m):
+        verts, density = dense_core_stats(g)
+        n_act = len(verts)
+        if n_act <= dense_max_n and density >= dense_threshold:
+            from repro.kernels.triangle_count.ops import dense_edge_support
 
-        relabel = np.zeros(int(verts.max()) + 1, np.int64)
-        relabel[verts] = np.arange(n_act)
-        compact = relabel[g.edges.astype(np.int64)].astype(np.int32)
-        use_kernel = jax.default_backend() == "tpu"
-        return dense_edge_support(
-            n_act, compact, use_kernel=use_kernel, interpret=not use_kernel
-        )
-    return np.asarray(edge_support_jax(g)).astype(np.int64)
+            relabel = np.zeros(int(verts.max()) + 1, np.int64)
+            relabel[verts] = np.arange(n_act)
+            compact = relabel[g.edges.astype(np.int64)].astype(np.int32)
+            use_kernel = jax.default_backend() == "tpu"
+            return dense_edge_support(
+                n_act, compact, use_kernel=use_kernel,
+                interpret=not use_kernel)
+        return np.asarray(edge_support_jax(g)).astype(np.int64)
 
 
 # ---------------------------------------------------------------------------

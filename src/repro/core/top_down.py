@@ -49,6 +49,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core import graph as glib
+from repro.core import spans
 from repro.core.bottom_up import (OocStats, RoundJournal, _Engine,
                                   _retry_candidate_peel, _run_key,
                                   partitioned_support)
@@ -109,6 +110,7 @@ class TopDownResult:
     stats: Optional[OocStats] = None
 
 
+@spans.job("top-down")
 def top_down_decompose(
     n: int,
     edges: np.ndarray,
@@ -260,30 +262,32 @@ def top_down_decompose(
         ``local_threshold_peel``.  Returns None when no undecided alive
         edge has psi >= k_b.
         """
-        undecided_b = alive_l & ~classified_l
-        elig = undecided_b & (psi_l >= k_b)
-        if not elig.any():
-            return None
-        u_k = np.zeros(n, dtype=bool)
-        eg = edges_l[elig]
-        u_k[eg[:, 0]] = True
-        u_k[eg[:, 1]] = True
-        u_in = u_k[edges_l[:, 0]]
-        v_in = u_k[edges_l[:, 1]]
-        in_h = alive_l & (u_in | v_in)
-        internal = u_in & v_in           # re-masked by alive at use time
-        if faithful_proc8:
-            cand_set = in_h
-        else:
-            # exclude external unclassified support (see module docstring)
-            cand_set = ((internal & alive_l & ~classified_l)
-                        | (classified_l & in_h))
-        # Compact the candidate to local edge ids and filter its triangles
-        # (part-local compaction shared with the partition-batch engine).
-        h_l = np.nonzero(cand_set)[0]
-        tmask = (cand_set[tris_l[:, 0]] & cand_set[tris_l[:, 1]]
-                 & cand_set[tris_l[:, 2]])
-        tris_loc = glib.compact_index(h_l, tris_l[tmask])
+        with spans.span("candidate_build", k=int(k_b)) as sp:
+            undecided_b = alive_l & ~classified_l
+            elig = undecided_b & (psi_l >= k_b)
+            if not elig.any():
+                return None
+            u_k = np.zeros(n, dtype=bool)
+            eg = edges_l[elig]
+            u_k[eg[:, 0]] = True
+            u_k[eg[:, 1]] = True
+            u_in = u_k[edges_l[:, 0]]
+            v_in = u_k[edges_l[:, 1]]
+            in_h = alive_l & (u_in | v_in)
+            internal = u_in & v_in           # re-masked by alive at use time
+            if faithful_proc8:
+                cand_set = in_h
+            else:
+                # exclude external unclassified support (see module docstring)
+                cand_set = ((internal & alive_l & ~classified_l)
+                            | (classified_l & in_h))
+            # Compact the candidate to local edge ids and filter its triangles
+            # (part-local compaction shared with the partition-batch engine).
+            h_l = np.nonzero(cand_set)[0]
+            tmask = (cand_set[tris_l[:, 0]] & cand_set[tris_l[:, 1]]
+                     & cand_set[tris_l[:, 2]])
+            tris_loc = glib.compact_index(h_l, tris_l[tmask])
+            sp.count(edges=len(h_l))
         return k_b, h_l, tris_loc, internal, int(in_h.sum())
 
     pre = None          # candidate pre-built while the previous level peeled
@@ -336,8 +340,10 @@ def top_down_decompose(
             dispatch_exc = exc          # enters the retry ladder below
         if not faithful_proc8:
             pre = build_candidate(k - 1)
-        ta = (alive_l[tris_l[:, 0]] & alive_l[tris_l[:, 1]]
-              & alive_l[tris_l[:, 2]])
+        # the prune's alive-triangle sweep, while the device peels
+        with spans.span("prune", k=int(k)):
+            ta = (alive_l[tris_l[:, 0]] & alive_l[tris_l[:, 1]]
+                  & alive_l[tris_l[:, 2]])
         try:
             if dispatch_exc is not None:
                 raise dispatch_exc
@@ -359,7 +365,7 @@ def top_down_decompose(
                 s, _ = h.result()
                 return s
             surv_l = _retry_candidate_peel(eng, stats, exc, redispatch,
-                                           max_retries)
+                                           max_retries, stage="td")
         phi_k = np.zeros(gnew.m, dtype=bool)
         phi_k[h_l[surv_l]] = True
         phi_k &= tentative
@@ -368,13 +374,17 @@ def top_down_decompose(
             classified_l |= phi_k
             phi[gnew_ids[phi_k]] = k
             # Steps 7-9: prune classified edges with no undecided triangle.
-            und = alive_l & ~classified_l
-            tri_needs = ta & (und[tris_l[:, 0]] | und[tris_l[:, 1]]
-                              | und[tris_l[:, 2]])
-            needs = np.zeros(gnew.m, dtype=np.int64)
-            np.add.at(needs, tris_l.reshape(-1), np.repeat(tri_needs, 3))
-            prunable = alive_l & classified_l & (needs == 0)
-            pruned_total += int(prunable.sum())
+            with spans.span("prune", k=int(k)) as sp:
+                und = alive_l & ~classified_l
+                tri_needs = ta & (und[tris_l[:, 0]] | und[tris_l[:, 1]]
+                                  | und[tris_l[:, 2]])
+                needs = np.zeros(gnew.m, dtype=np.int64)
+                np.add.at(needs, tris_l.reshape(-1),
+                          np.repeat(tri_needs, 3))
+                prunable = alive_l & classified_l & (needs == 0)
+                pruned = int(prunable.sum())
+                sp.count(pruned=pruned)
+            pruned_total += pruned
             alive_l &= ~prunable
         if journal is not None:
             journal.record(

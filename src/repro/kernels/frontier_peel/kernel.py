@@ -43,6 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # leave headroom below the ~16 MB/core
 DEFAULT_TILE_CANDIDATES = (128, 256, 512, 1024)
+# the pallas_call's name: stable, so a trace reader can find the kernel by it
+KERNEL_NAME = "fused_round"
 
 
 def kernel_vmem_bytes(cap_e: int, bt: int) -> int:
@@ -140,6 +142,7 @@ def fused_round(sup, alive, rm, tris, *, bt: int = 256,
     row = pl.BlockSpec((1, 1, cap_e), lambda i, j: (i, 0, 0))
     sup_out, alive_out = pl.pallas_call(
         _round_kernel,
+        name=KERNEL_NAME,
         grid=grid,
         in_specs=[row, row, row,
                   pl.BlockSpec((1, bt, 3), lambda i, j: (i, j, 0))],
