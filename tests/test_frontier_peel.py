@@ -1,10 +1,12 @@
 """Frontier-compacted peel engine + skew-aware support (DESIGN.md §3-§4)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import graph as glib
+from repro.core import peel as peel_mod
 from repro.core.peel import (peel_classes, peel_classes_dense, peel_threshold,
                              peel_threshold_dense, truss_decompose)
 from repro.core.serial import alg2_truss
@@ -167,3 +169,108 @@ class TestFrontierPeel:
             row = tids[indptr[eid]:indptr[eid + 1]]
             assert set(row) == {t for t in range(len(tris))
                                 if eid in tris[t]}
+
+
+# ---------------------------------------------------------------------------
+# the round's slot-to-segment lookup (ragged -> flat expansion)
+# ---------------------------------------------------------------------------
+
+_M = 1000  # edge-id pad of the compacted frontier (f_ids == m past its end)
+
+
+def _segments(rng, case, cap_f, cap_t):
+    """``(f_ids, lens)`` as ``_frontier_round`` builds them: ascending edge
+    ids, then the pad id with length 0."""
+    nf = {"empty": 0, "fills_cap_t": 1}.get(case, int(rng.integers(1, cap_f)))
+    f_ids = np.full(cap_f, _M, np.int32)
+    f_ids[:nf] = np.sort(rng.choice(_M, nf, replace=False))
+    lens = np.zeros(cap_f, np.int32)
+    if case == "ragged":     # empty rows among them, all ends inside cap_t
+        lens[:nf] = rng.integers(0, 4, nf) * (rng.random(nf) < 0.6)
+    elif case == "past_cap_t":
+        lens[:nf] = rng.integers(0, 3 * cap_t // max(nf, 1), nf)
+        lens[: nf // 4] = 0
+    elif case == "fills_cap_t":
+        lens[0] = cap_t
+    return f_ids, lens
+
+
+def _lookup_by_search(f_ids, lens, indptr, cap_t):
+    """The binary-search form: the segment index, its edge id, its start,
+    and its edge's incidence row start."""
+    offs = jnp.cumsum(lens)
+    s = jnp.arange(cap_t, dtype=jnp.int32)
+    jc = jnp.minimum(jnp.searchsorted(offs, s, side="right"),
+                     f_ids.shape[0] - 1).astype(jnp.int32)
+    f = f_ids[jc]
+    return jc, f, offs[jc] - lens[jc], indptr[jnp.minimum(f, _M - 1)]
+
+
+def _lookup_by_scan(f_ids, lens, indptr, cap_t):
+    starts = jnp.cumsum(lens) - lens
+    seg = jnp.arange(f_ids.shape[0], dtype=jnp.int32)
+    rows = indptr[jnp.minimum(f_ids, _M - 1)]
+    return tuple(peel_mod._slot_owner(starts, v, cap_t)
+                 for v in (seg, f_ids, starts, rows))
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["one", "vmap"])
+@pytest.mark.parametrize("case", ["ragged", "past_cap_t", "empty",
+                                  "fills_cap_t"])
+def test_slot_owner_matches_searchsorted(rng, case, batched):
+    """Every gather slot gets the same segment, owner edge id, segment
+    start and incidence row start from the scatter-and-prefix-sum lookup
+    as from the search."""
+    cap_f, cap_t = 64, 256
+    draws = [_segments(rng, case, cap_f, cap_t)
+             for _ in range(6 if batched else 1)]
+    f_ids = jnp.asarray(np.stack([d[0] for d in draws]))
+    lens = jnp.asarray(np.stack([d[1] for d in draws]))
+    indptr = jnp.asarray(np.cumsum(rng.integers(0, 5, (len(draws), _M + 1)),
+                                   axis=1, dtype=np.int32))
+    args = (f_ids, lens, indptr)
+    want_fn = lambda *a: _lookup_by_search(*a, cap_t)  # noqa: E731
+    got_fn = lambda *a: _lookup_by_scan(*a, cap_t)  # noqa: E731
+    if batched:
+        want, got = jax.vmap(want_fn)(*args), jax.vmap(got_fn)(*args)
+    else:
+        want, got = (want_fn(*(a[0] for a in args)),
+                     got_fn(*(a[0] for a in args)))
+    for w, g in zip(want, got):
+        assert np.array_equal(np.asarray(w), np.asarray(g))
+
+
+@pytest.mark.parametrize("n", [1, 127, 128, 129, 16384 + 5, 65536])
+def test_prefix_sum_matches_cumsum(rng, n):
+    """The matmul prefix sum equals ``cumsum`` bit for bit, wrapping on
+    int32 overflow alike."""
+    x = rng.integers(-2**31, 2**31, n, dtype=np.int64).astype(np.int32)
+    assert np.array_equal(np.asarray(peel_mod._prefix_sum(jnp.asarray(x))),
+                          np.cumsum(x, dtype=np.int32))
+
+
+def _lower_peel(name, m=48, T=32, cap_f=16, cap_t=128, lanes=4):
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    b = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)  # noqa: E731
+    caps = dict(cap_f=cap_f, cap_t=cap_t)
+    graph = (i32(T, 3), i32(m + 1), i32(3 * T))
+    if name == "peel_classes_fixedcap":
+        return peel_mod.peel_classes_fixedcap.lower(
+            i32(m), *graph, b(m), i32(m), i32(), i32(peel_mod.N_STATS),
+            **caps)
+    if name == "peel_threshold_fixedcap":
+        return peel_mod.peel_threshold_fixedcap.lower(
+            i32(m), *graph, b(m), b(m), i32(), i32(peel_mod.N_STATS), **caps)
+    return peel_mod._peel_classes_vmapped.lower(
+        i32(lanes, m), *(jax.ShapeDtypeStruct((lanes,) + g.shape, g.dtype)
+                         for g in graph), b(lanes, m), **caps)
+
+
+@pytest.mark.parametrize("name", ["peel_classes_fixedcap",
+                                  "peel_threshold_fixedcap",
+                                  "_peel_classes_vmapped"])
+def test_peel_program_has_no_loop_inside_the_round(name):
+    """Each peel program is its outer frontier loop and nothing loops
+    inside a round: a per-slot search (``jnp.searchsorted`` lowers to a
+    while loop) must not come back into the round unseen."""
+    assert _lower_peel(name).as_text().count("stablehlo.while") == 1

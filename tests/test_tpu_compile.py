@@ -6,7 +6,8 @@ the Mosaic tiling rule, or more VMEM than a kernel may use.  These tests
 lower and compile each kernel at the shapes ``chip_smoke.py`` drives —
 tiles from ``resolve_tile`` / ``feasible_tiles`` — for one chip of a
 ``v5e:2x2`` topology that is described, not attached, and check that the
-kernel survived as a ``tpu_custom_call``.  Nothing runs.
+kernel survived as a ``tpu_custom_call``; the in-memory XLA frontier loop
+compiles at the in-memory benchmark graph's shapes.  Nothing runs.
 
 The topology is described inside a fixture (never at import), so every
 pytest-xdist worker collects the same tests and only the worker given this
@@ -22,7 +23,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import distributed
+from repro.core import distributed, peel
 from repro.kernels.frontier_peel import kernel as fk
 from repro.kernels.frontier_peel import ops
 from repro.kernels.triangle_count.kernel import triangle_count_kernel
@@ -34,6 +35,12 @@ CAP_T = 65536
 # frontier capacities peel_classes_batched derives for such a bucket
 CAP_F = 512
 CAP_INC = 16384
+# the in-memory benchmark graph (Graph 500 scale 13) and the frontier
+# capacities _default_caps gives it
+INMEM_M = 102075
+INMEM_T = 1174267
+INMEM_CAP_F = 4096
+INMEM_CAP_T = 65536
 
 
 @pytest.fixture(scope="module")
@@ -140,6 +147,19 @@ def test_peel_threshold_fused_loop_compiles(one_chip):
         row, _spec(one_chip, (1, CAP_T, 3)), row, row,
         _spec(one_chip, ()), bt=bt, interpret=False).compile()
     _assert_kernel(compiled)
+
+
+def test_peel_classes_frontier_loop_compiles(one_chip):
+    """The in-memory XLA frontier loop at the benchmark graph's shapes: one
+    while loop, with no search loop inside its rounds."""
+    m, T = INMEM_M, INMEM_T
+    compiled = peel.peel_classes_fixedcap.lower(
+        _spec(one_chip, (m,)), _spec(one_chip, (T, 3)),
+        _spec(one_chip, (m + 1,)), _spec(one_chip, (3 * T,)),
+        _spec(one_chip, (m,), jnp.bool_), _spec(one_chip, (m,)),
+        _spec(one_chip, ()), _spec(one_chip, (peel.N_STATS,)),
+        cap_f=INMEM_CAP_F, cap_t=INMEM_CAP_T).compile()
+    assert compiled.as_text().count(" while(") == 1
 
 
 def test_triangle_count_kernel_compiles(one_chip):
