@@ -67,8 +67,9 @@ from repro.core import faults
 from repro.core import graph as glib
 from repro.core import partition as plib
 from repro.core.store import GraphStore
-from repro.core.peel import (local_threshold_peel, peel_classes,
-                             peel_classes_batched, peel_threshold)
+from repro.core.peel import (_mesh_axes, _mesh_size, local_threshold_peel,
+                             peel_classes, peel_classes_batched,
+                             peel_threshold)
 from repro.core.spans import span
 from repro.core import support as sup_lib
 from repro.core.support import (list_triangles, list_triangles_np,
@@ -119,14 +120,7 @@ class _Engine:
     @property
     def devices(self) -> int:
         """Total devices spanned: the product over every named mesh axis."""
-        if self.mesh is None:
-            return 1
-        axes = ((self.mesh_axis,) if isinstance(self.mesh_axis, str)
-                else tuple(self.mesh_axis))
-        d = 1
-        for a in axes:
-            d *= int(self.mesh.shape[a])
-        return d
+        return _mesh_devices(self.mesh, self.mesh_axis)
 
 
 def _mesh_devices(mesh, mesh_axis) -> int:
@@ -135,11 +129,7 @@ def _mesh_devices(mesh, mesh_axis) -> int:
     axis size, keeping single-axis checkpoint run keys unchanged."""
     if mesh is None:
         return 1
-    axes = (mesh_axis,) if isinstance(mesh_axis, str) else tuple(mesh_axis)
-    d = 1
-    for a in axes:
-        d *= int(mesh.shape[a])
-    return d
+    return _mesh_size(mesh, _mesh_axes(mesh_axis))
 
 
 def _accepts_round(fn) -> bool:
@@ -305,6 +295,10 @@ class OocStats:
     lanes_per_shard: int = 0  # lane rows each device held in that bucket
     h2d_bytes: int = 0        # graph bytes the dispatches copied host to
     #                           device (their truss.upload spans)
+    mesh_lanes: int = 0       # lanes of the buckets split over a mesh, at
+    #                           the device multiple they were dispatched at
+    mesh_pad_lanes: int = 0   # of those, lanes holding no live edge: the
+    #                           dead lanes the split added (DESIGN.md §10)
 
     @property
     def prefetch_hit_rate(self) -> float:
@@ -359,9 +353,12 @@ class OocStats:
     def count_lanes(self, handle) -> None:
         """Credit one dispatch's lanes to the engine it took
         (``PendingPeel.engine``); host short-circuits count as neither.
-        The first mesh-split bucket also records its lane split.  The
-        dispatch's uploaded bytes go to ``h2d_bytes``."""
+        The first mesh-split bucket also records its lane split, and
+        every one its lanes and dead lanes.  The dispatch's uploaded bytes
+        go to ``h2d_bytes``."""
         self.h2d_bytes += handle.h2d_bytes
+        self.mesh_lanes += handle.mesh_lanes
+        self.mesh_pad_lanes += handle.pad_lanes
         if handle.engine == "pallas":
             self.pallas_lanes += handle.lanes
             self.pallas_max_lanes = max(self.pallas_max_lanes, handle.lanes)

@@ -49,9 +49,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.partition import round_up_to_multiple
-from repro.core.peel import (N_STATS, _frontier_round,
+from repro.core.peel import (N_STATS, _frontier_round, _mesh_size,
                              _peel_classes_vmapped_impl,
                              peel_classes_fixedcap)
+from repro.core.spans import upload
 from repro.core.support import _pow2_ceil, triangle_incidence_np
 
 _BIG = jnp.int32(np.iinfo(np.int32).max // 2)
@@ -194,12 +195,14 @@ def shard_incidence(tris: np.ndarray, m: int, n_shards: int):
     ``tris`` (T_pad, 3) with T_pad divisible by ``n_shards``; triangle ids in
     each shard's CSR are LOCAL to the shard (matching the tris rows that
     shard_map hands each device).  Returns (indptr_s (S, m+1), tids_s (S, L))
-    padded to a common L.
+    with L = 3 * T_pad / S, the most incidence slots a shard can hold: the
+    shapes follow the padded triangle list's and not its contents, so
+    candidates padded to one shape share one compiled program.
     """
     t_loc = len(tris) // n_shards
     per = [triangle_incidence_np(tris[i * t_loc:(i + 1) * t_loc], m)
            for i in range(n_shards)]
-    L = max([len(t) for _, t in per] + [1])
+    L = max(3 * t_loc, 1)
     indptr_s = np.zeros((n_shards, m + 1), np.int32)
     tids_s = np.zeros((n_shards, L), np.int32)
     for i, (indptr, tids) in enumerate(per):
@@ -324,15 +327,15 @@ def shard_incidence_lanes(tris_b: np.ndarray, cap_e: int, n_shards: int):
     ``tris_b`` (B, T, 3) with T divisible by ``n_shards``; triangle ids in
     each shard's CSR are LOCAL to the (lane, shard) rows shard_map hands
     each device.  Returns (indptr_ls (B, S, cap_e+1), tids_ls (B, S, L))
-    padded to a common L across lanes AND shards (one static shape per
-    bucket).
+    with L = 3 * T / S, the most incidence slots a (lane, shard) can hold:
+    one static shape per bucket shape, whatever the triangles.
     """
     B, T = tris_b.shape[0], tris_b.shape[1]
     t_loc = T // n_shards
     per = [[triangle_incidence_np(tris_b[b, i * t_loc:(i + 1) * t_loc],
                                   cap_e)
             for i in range(n_shards)] for b in range(B)]
-    L = max([len(t) for row in per for _, t in row] + [1])
+    L = max(3 * t_loc, 1)
     indptr_ls = np.zeros((B, n_shards, cap_e + 1), np.int32)
     tids_ls = np.zeros((B, n_shards, L), np.int32)
     for b in range(B):
@@ -417,7 +420,8 @@ def peel_classes_batched_sharded(mesh, sup_b, tris_b, indptr_b, tids_b,
 
     Returns DEVICE arrays ``(phi, stats)`` over the PADDED lane count —
     still futures at return time, so the caller's host work overlaps the
-    pod-wide peel; slice back to the original B when materializing.
+    pod-wide peel; slice back to the original B when materializing — and
+    the bytes the inputs' copy to the device took (one ``truss.upload``).
     """
     axes = _axes_tuple(axis)
     n_lane = int(mesh.shape[axes[0]])
@@ -426,7 +430,8 @@ def peel_classes_batched_sharded(mesh, sup_b, tris_b, indptr_b, tids_b,
         round_up_to_multiple(sup_b.shape[0], n_lane))
     if len(axes) == 1:
         fn = _batched_sharded_fn(mesh, axes[0], int(cap_f), int(cap_t))
-        return fn(*(jnp.asarray(a) for a in arrs))
+        args, h2d = upload(*arrs)
+        return (*fn(*args), h2d)
     lane_axis, tri_axis = axes
     n_tri = int(mesh.shape[tri_axis])
     sup_p, tris_p, _, _, alive_p = arrs
@@ -440,9 +445,9 @@ def peel_classes_batched_sharded(mesh, sup_b, tris_b, indptr_b, tids_b,
         np.asarray(tris_p), cap_e, n_tri)
     fn = _batched_sharded2_fn(mesh, lane_axis, tri_axis,
                               int(cap_f), int(cap_t))
-    return fn(jnp.asarray(sup_p), jnp.asarray(tris_p),
-              jnp.asarray(indptr_ls), jnp.asarray(tids_ls),
-              jnp.asarray(alive_p))
+    args, h2d = upload(sup_p, np.asarray(tris_p), indptr_ls, tids_ls,
+                       alive_p)
+    return (*fn(*args), h2d)
 
 
 @lru_cache(maxsize=None)
@@ -500,21 +505,19 @@ def local_threshold_peel_sharded(mesh, sup0, tris, alive0, removable, thresh,
     those mesh axes (DESIGN.md §13) — one huge candidate peel spreads its
     psum volume across the whole multi-axis mesh.
 
-    Returns ``(alive_device_array, cap_f, cap_t)``; the caps feed the
-    caller's compile-shape cache key.
+    Returns ``(alive_device_array, cap_f, cap_t, h2d_bytes)``; the caps
+    feed the caller's compile-shape cache key, and ``h2d_bytes`` is what
+    the inputs' copy to the device took (one ``truss.upload``).
     """
     axes = _axes_tuple(axis)
-    n_shards = 1
-    for a in axes:
-        n_shards *= int(mesh.shape[a])
+    n_shards = _mesh_size(mesh, axes)
     spec_axis = axes[0] if len(axes) == 1 else axes
     m = int(sup0.shape[0])
     tris_np = np.asarray(tris)
     indptr_s, tids_s = shard_incidence(tris_np, m, n_shards)
     cap_f, cap_t = _sharded_caps(m, indptr_s, tids_s)
     fn = _threshold_sharded_fn(mesh, spec_axis, int(cap_f), int(cap_t))
-    alive = fn(jnp.asarray(sup0), jnp.asarray(tris_np),
-               jnp.asarray(indptr_s), jnp.asarray(tids_s),
-               jnp.asarray(alive0), jnp.asarray(removable),
-               jnp.int32(thresh))
-    return alive, cap_f, cap_t
+    args, h2d = upload(np.asarray(sup0), tris_np, indptr_s, tids_s,
+                       np.asarray(alive0), np.asarray(removable))
+    alive = fn(*args, jnp.int32(thresh))
+    return alive, cap_f, cap_t, h2d

@@ -520,13 +520,16 @@ class PendingPeel:
     number of distinct lane slices the devices hold and the lane rows of
     each; ``None`` for a single-device dispatch.  ``h2d_bytes`` is the
     graph bytes the dispatch copied to the device (its ``truss.upload``
-    spans).
+    spans).  ``mesh_lanes`` is the lane count a bucket split over a mesh
+    was dispatched at (a multiple of the lane axis), and ``pad_lanes`` how
+    many of those lanes hold no live edge; both 0 for any other dispatch.
     """
 
     def __init__(self, finalize, new_compile: bool, sharded: bool = False,
                  fault_ctx: Optional[dict] = None, engine: str = "xla",
                  lanes: int = 1, lane_split: Optional[tuple] = None,
-                 h2d_bytes: int = 0):
+                 h2d_bytes: int = 0, mesh_lanes: int = 0,
+                 pad_lanes: int = 0):
         self._finalize = finalize
         self.new_compile = bool(new_compile)
         self.sharded = bool(sharded)
@@ -534,6 +537,8 @@ class PendingPeel:
         self.lanes = int(lanes)
         self.lane_split = lane_split
         self.h2d_bytes = int(h2d_bytes)
+        self.mesh_lanes = int(mesh_lanes)
+        self.pad_lanes = int(pad_lanes)
         self._fault_ctx = fault_ctx
         self._out = None
         self._error = None
@@ -563,6 +568,14 @@ def _mesh_axes(mesh_axis) -> tuple:
     if isinstance(mesh_axis, str):
         return (mesh_axis,)
     return tuple(mesh_axis)
+
+
+def _mesh_size(mesh, axes: tuple) -> int:
+    """Devices the named ``axes`` of ``mesh`` span: their sizes' product."""
+    n = 1
+    for a in axes:
+        n *= int(mesh.shape[a])
+    return n
 
 
 def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
@@ -650,6 +663,9 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         # key on the PADDED lane count — that is the shape jit compiles
         # (the counter must stay <= the true number of XLA compiles)
         B_pad = round_up_to_multiple(B, n_lane)
+        # dead lanes of the split: those the packer added to reach the
+        # device multiple and those pad_bucket_lanes appends
+        pad_lanes = B_pad - int(np.asarray(alive_b).any(axis=1).sum())
         key = ((B_pad,) + tuple(sup_b.shape[1:]),
                (B_pad,) + tuple(tris_b.shape[1:]),
                cap_f, cap_t,
@@ -657,9 +673,10 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
         new = shape_cache is not None and key not in shape_cache
         if shape_cache is not None:
             shape_cache.add(key)
-        # the sharded dispatch copies its inputs itself
-        with span("dispatch", engine="mesh", lanes=B, new_compile=new):
-            phi_d, st_d = peel_classes_batched_sharded(
+        # the sharded dispatch copies its padded inputs itself
+        with span("dispatch", engine="mesh", lanes=B, new_compile=new,
+                  devices=_mesh_size(mesh, axes), pad_lanes=pad_lanes):
+            phi_d, st_d, h2d = peel_classes_batched_sharded(
                 mesh, np.asarray(sup_b), tris_np, np.asarray(indptr_b),
                 np.asarray(tids_b), np.asarray(alive_b),
                 cap_f=cap_f, cap_t=cap_t, axis=mesh_axis)
@@ -675,7 +692,8 @@ def peel_classes_batched(sup_b, tris_b, indptr_b, tids_b, alive_b,
                           int(phi_d.sharding.shard_shape(shape)[0]))
             return PendingPeel(_finish, new, sharded=True,
                                fault_ctx=fault_ctx, lanes=B,
-                               lane_split=lane_split)
+                               lane_split=lane_split, h2d_bytes=h2d,
+                               mesh_lanes=B_pad, pad_lanes=pad_lanes)
         with span("device_wait"):
             phi, st = _finish()
         return phi, st, new
@@ -791,9 +809,7 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
         from repro.core.partition import round_up_to_multiple
 
         axes = _mesh_axes(mesh_axis)
-        n_dev = 1
-        for a in axes:
-            n_dev *= int(mesh.shape[a])
+        n_dev = _mesh_size(mesh, axes)
         # shape ladder (DESIGN.md §13): if an already-compiled sharded
         # shape (read back off the caller's shape_cache keys — stage-2
         # mesh keys are the int-headed 5-tuples) can hold this candidate,
@@ -824,8 +840,9 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
     rem_p[:m] = removable
     if mesh is not None:
         # the sharded dispatch copies its inputs itself
-        with span("dispatch", engine="mesh", lanes=1) as sp:
-            alive_dev, cap_f, cap_t = local_threshold_peel_sharded(
+        with span("dispatch", engine="mesh_threshold", lanes=1,
+                  devices=n_dev, tri_rows=cap_tri // n_dev) as sp:
+            alive_dev, cap_f, cap_t, h2d = local_threshold_peel_sharded(
                 mesh, sup_p, tris_p, alive_p, rem_p, thresh, axis=mesh_axis)
             key = (cap_e, cap_tri, cap_f, cap_t, ("mesh", n_dev))
             new = shape_cache is not None and key not in shape_cache
@@ -839,7 +856,7 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
 
         if not blocking:
             return PendingPeel(_finish_sharded, new, sharded=True,
-                               fault_ctx=fault_ctx)
+                               fault_ctx=fault_ctx, h2d_bytes=h2d)
         with span("device_wait"):
             alive, removed = _finish_sharded()
         return alive, removed, new

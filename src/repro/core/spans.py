@@ -20,7 +20,12 @@ the event's stats.  ``NAMES`` is the contract trace readers rely on:
   candidate_build  one stage-2 / top-down level's candidate: k, edges
   prune            top-down Steps 7-9, classified edges off every undecided
                    triangle: k, pruned (on the half after the wait)
-  dispatch         enqueue of a device peel: engine, lanes, new_compile
+  dispatch         enqueue of a device peel: engine, lanes, new_compile;
+                   over a mesh also devices, and pad_lanes (engine
+                   "mesh", a bucket's lanes split over the chips: its
+                   lanes holding no live edge) or tri_rows (engine
+                   "mesh_threshold", a triangle-sharded candidate peel:
+                   triangle rows a device holds)
   device_wait      the host blocked on a device result (and its copy back);
                    the in-memory wait counts resumes
   retry            one attempt of a retry ladder: stage, attempt

@@ -147,13 +147,37 @@ def test_local_threshold_peel_sharded_matches(rng, mesh):
         assert (rem_s == rem_1).all(), thresh
 
 
+def test_shard_incidence_width_follows_the_padded_shape(rng):
+    """Two candidates padded to one shape get incidence CSRs of one shape,
+    whatever their triangles, so the sharded peel compiled for the first
+    serves the second: each shard's width is the most it can hold, 3 T / S.
+    """
+    from repro.core.distributed import (shard_incidence,
+                                        shard_incidence_lanes)
+
+    m, T, S = 40, 16, 4
+    dense = rng.integers(0, m, size=(T, 3)).astype(np.int32)
+    sparse = np.full((T, 3), m, np.int32)       # drop-slot rows after two
+    sparse[:2] = dense[:2]
+    for tris in (dense, sparse):
+        indptr_s, tids_s = shard_incidence(tris, m, S)
+        assert indptr_s.shape == (S, m + 1)
+        assert tids_s.shape == (S, 3 * T // S)
+        indptr_ls, tids_ls = shard_incidence_lanes(
+            np.stack([tris, dense]), m, S)
+        assert tids_ls.shape == (2, S, 3 * T // S)
+        # the slots past each shard's own incidence stay zero
+        counts = indptr_s[:, -1]
+        assert all((tids_s[i, c:] == 0).all() for i, c in enumerate(counts))
+
+
 # ---------------------------------------------------------------------------
 # forced 8-device corpus equality (subprocess: device count locks at init)
 # ---------------------------------------------------------------------------
 
-def _run(code: str, timeout=560):
+def _run(code: str, timeout=560, devices=8):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
@@ -228,6 +252,34 @@ def test_sharded_rounds_8_devices():
         print("SHARDED-OOC-OK")
     """)
     assert "SHARDED-OOC-OK" in out
+
+
+def test_mesh_run_counts_its_uploads_and_dead_lanes_4_devices():
+    """Over a 4-device mesh every dispatch copies its own (padded) inputs,
+    and they count in ``h2d_bytes``: at least the one-device batched run's
+    bytes on the same graph.  The lanes split over the mesh and the dead
+    ones among them are counted."""
+    out = _run("""
+        import jax, numpy as np
+        from repro.core.peel import truss_decompose
+        from repro.data import graphgen
+        n, ce = graphgen.rmat(7, 16, seed=0, a=0.57, b=0.19, c=0.19)
+        kw = dict(engine="bottom-up", memory_budget=2048, kernel="xla",
+                  with_stats=True)
+        phi_1, one = truss_decompose(n, ce, **kw)
+        mesh = jax.make_mesh((4,), ("data",))
+        phi_4, four = truss_decompose(n, ce, mesh=mesh, **kw)
+        assert (phi_1 == phi_4).all()
+        assert (four.devices, four.lane_shards) == (4, 4)
+        assert four.retries == four.degraded == 0
+        assert four.h2d_bytes >= one.h2d_bytes > 0
+        assert four.mesh_lanes % 4 == 0 and four.mesh_lanes > 0
+        assert 0 <= four.mesh_pad_lanes < four.mesh_lanes
+        assert one.mesh_lanes == one.mesh_pad_lanes == 0
+        print("MESH-H2D-OK", one.h2d_bytes, four.h2d_bytes,
+              four.mesh_lanes, four.mesh_pad_lanes)
+    """, devices=4)
+    assert "MESH-H2D-OK" in out
 
 
 # ---------------------------------------------------------------------------

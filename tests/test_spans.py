@@ -1,8 +1,8 @@
 """The program's ``truss.*`` host spans (``repro.core.spans``), recorded
-under a CPU profiler session around four small jobs: in memory, bottom-up
-with the XLA lanes, top-down with a budget, and top-down reached through
-``truss_decompose``.  One file, so that a single test worker holds the one
-profiler session."""
+under a CPU profiler session around five small jobs: in memory, bottom-up
+with the XLA lanes, top-down with a budget, top-down reached through
+``truss_decompose``, and bottom-up over a mesh of the devices there are.
+One file, so that a single test worker holds the one profiler session."""
 
 from __future__ import annotations
 
@@ -42,6 +42,10 @@ JOBS = {
     "routed": lambda: truss_decompose(
         N, EDGES, engine="top-down", memory_budget=BUDGET, kernel="xla",
         with_stats=True),
+    "mesh": lambda: truss_decompose(
+        N, EDGES, engine="bottom-up", memory_budget=BUDGET, kernel="xla",
+        mesh=jax.make_mesh((len(jax.devices()),), ("data",)),
+        with_stats=True),
 }
 # spans each path must open; others may appear (a retry, say)
 EXPECTED = {
@@ -55,6 +59,7 @@ EXPECTED = {
                  "candidate_build", "prune", "dispatch", "device_wait"},
 }
 EXPECTED["routed"] = EXPECTED["top_down"]
+EXPECTED["mesh"] = EXPECTED["bottom_up"]
 
 
 class _Span:
@@ -117,7 +122,8 @@ def test_each_path_opens_its_spans(traced, kind):
     names = {s.name for s in per_job[kind]}
     assert EXPECTED[kind] <= names
     job, = [s for s in per_job[kind] if s.name == "job"]
-    engine = {"inmem": "auto", "bottom_up": "bottom-up"}.get(kind, "top-down")
+    engine = {"inmem": "auto", "bottom_up": "bottom-up",
+              "mesh": "bottom-up"}.get(kind, "top-down")
     assert job.stats == {"engine": engine, "n": N, "m": len(EDGES)}
 
 
@@ -172,6 +178,30 @@ def test_partition_rounds_count_their_lanes(traced):
     dispatches = [s for s in per_job["bottom_up"] if s.name == "dispatch"]
     assert {s.stats["engine"] for s in dispatches} == {"xla"}
     assert sum(s.stats["lanes"] for s in dispatches) == stats.xla_lanes
+
+
+def test_mesh_dispatches_count_their_devices_and_dead_lanes(traced):
+    _, on, _, per_job = traced
+    stats = on["mesh"][1]
+    devices = len(jax.devices())
+    dispatches = [s for s in per_job["mesh"] if s.name == "dispatch"]
+    lanes = [s for s in dispatches if s.stats["engine"] == "mesh"]
+    threshold = [s for s in dispatches
+                 if s.stats["engine"] == "mesh_threshold"]
+    assert lanes and threshold
+    assert len(lanes) + len(threshold) == len(dispatches)
+    assert all(s.stats["devices"] == devices for s in dispatches)
+    # bottom-up's rounds pack whole multiples of the lane axis, so the
+    # dispatch pads none and its lanes are the counter's
+    assert sum(s.stats["lanes"] for s in lanes) == stats.mesh_lanes
+    assert sum(s.stats["pad_lanes"] for s in lanes) == stats.mesh_pad_lanes
+    assert 0 <= stats.mesh_pad_lanes < stats.mesh_lanes
+    assert stats.sharded_rounds == len(lanes) + len(threshold)
+    for s in threshold:
+        assert s.stats["tri_rows"] > 0 and s.stats["lanes"] == 1
+    # the lanes' inputs are copied inside their dispatch
+    uploads = [s for s in per_job["mesh"] if s.name == "upload"]
+    assert all(any(u.within(d) for d in dispatches) for u in uploads)
 
 
 def test_support_credit_counts_every_triangle_once(traced):
