@@ -67,6 +67,14 @@ def degrees(n: int, edges: np.ndarray) -> np.ndarray:
     return deg
 
 
+def csr_keys(indptr: np.ndarray, nbrs: np.ndarray, n: int) -> np.ndarray:
+    """Composite key row * n + nbr of every CSR entry, int64.  Rows come in
+    order and each row is sorted, so the keys ascend: a ``searchsorted``
+    into them is a membership test for any (row, nbr) pair."""
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+    return rows * np.int64(n) + nbrs
+
+
 class Graph:
     """Static-shape packed graph (all arrays numpy; moved to device lazily).
 
@@ -364,8 +372,7 @@ class Graph:
         # neighbor id); sort just the k new entries and splice them at
         # their searchsorted positions
         out_deg_old = (self.indptr[1:] - self.indptr[:-1]).astype(np.int64)
-        rows_old = np.repeat(np.arange(n, dtype=np.int64), out_deg_old)
-        key_old = rows_old * np.int64(n) + self.nbrs
+        key_old = csr_keys(self.indptr, self.nbrs, n)
         order = np.lexsort((ins_dst, ins_src))
         e_src, e_dst = ins_src[order], ins_dst[order]
         e_eid = new_id_ins[order]

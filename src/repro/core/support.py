@@ -6,13 +6,17 @@ The vectorized form keeps the paper's O(m^1.5) bound (Theorem 1):
   * every edge is oriented low-rank -> high-rank (rank = (deg, id) order), so
     out-degrees are O(sqrt(m));
   * for each oriented edge (a->b), every out-neighbor w of a is tested for
-    membership in N+(b) — a *binary search* into the sorted CSR row of b
-    (the TPU-idiomatic replacement for the paper's hashtable);
+    membership in N+(b) — a lookup in the sorted CSR (the TPU-idiomatic
+    replacement for the paper's hashtable);
   * a hit identifies triangle {a,b,w} exactly once (forward algorithm) and
     credits support to all three edge ids.
 
-Shapes are static: edges are processed in fixed-size chunks of C edges, each
-expanded to (C, D) wedge candidates.  A single global D = max oriented
+The host path expands each edge's wedges exactly, cut into chunks of a
+fixed wedge count, and finds every member with one ``searchsorted`` of the
+chunk's composite keys ``b * n + w`` into the graph's sorted CSR keys
+``row * n + nbr``.  The device path keeps static shapes: edges are processed
+in fixed-size chunks of C edges, each expanded to (C, D) wedge candidates
+searched by a per-row binary search.  A single global D = max oriented
 out-degree would let one hub vertex in a power-law graph inflate every chunk
 by orders of magnitude, so the device path is *skew-aware*: oriented edges
 are bucketed by the power-of-two out-degree of their source row and each
@@ -20,7 +24,9 @@ bucket runs the wedge enumeration with its own D (DESIGN.md §4).  Total work
 stays O(m^1.5); memory per bucket is O(C_b * D_b) with C_b sized to a fixed
 element budget.
 
-Three entry points share the same logic:
+Entry points:
+  * ``list_triangles_np`` / ``list_triangles`` — host triangle lists, in
+    edge-id order / in the device path's bucket order;
   * ``edge_support_np``   — numpy, host-side (oracle + preprocessing);
   * ``edge_support_jax``  — jit'd lax.scan over bucketed chunks (device path);
   * ``edge_support_auto`` — dispatch: dense-core partitions go to the
@@ -41,122 +47,102 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import Graph
+from repro.core.graph import Graph, csr_keys
 from repro.core.spans import span, upload
-
-
-def _search_iters(max_row: int) -> int:
-    return max(1, math.ceil(math.log2(max_row + 1))) if max_row > 0 else 1
 
 
 # ---------------------------------------------------------------------------
 # numpy path
 # ---------------------------------------------------------------------------
 
-def _row_lower_bound_np(nbrs, lo, hi, target, iters):
-    lo = lo.astype(np.int64).copy()
-    hi = hi.astype(np.int64).copy()
-    n_entries = len(nbrs)
-    for _ in range(iters):
-        mid = (lo + hi) >> 1
-        midc = np.minimum(mid, max(n_entries - 1, 0))
-        less = np.where(lo < hi, nbrs[midc] < target, False)
-        lo = np.where(less, mid + 1, lo)
-        hi = np.where(less, hi, np.where(lo < hi, mid, hi))
-    return lo
+# Wedges a host chunk expands at once: each per-wedge int64 array is 16 MiB,
+# below a 32 MiB malloc mmap threshold, so chunks reuse heap pages instead
+# of faulting in fresh ones.
+WEDGE_BUDGET = 1 << 21
 
 
-def _wedge_hits_np(g: Graph, e_lo: int, e_hi: int):
-    """For edge ids [e_lo, e_hi): returns (eid, e_aw, e_bw, hit) flat arrays."""
-    eids = np.arange(e_lo, e_hi, dtype=np.int64)
-    return _wedge_hits_ids_np(g, eids, g.max_out_deg)
+def _wedge_hits(g: Graph, eids: np.ndarray, budget: int):
+    """Yield the triangles the wedges of ``eids`` close, chunk by chunk.
 
-
-def _wedge_hits_ids_np(g: Graph, eids: np.ndarray, D: int):
-    """Wedge enumeration for an explicit edge-id set with wedge width ``D``.
-
-    ``D`` must cover the out-degree of every source row of ``eids`` — the
-    skew-aware callers pass a per-bucket ``D`` (DESIGN.md §4) instead of the
-    global ``max_out_deg``.
+    Edge e = (a->b) opens outdeg(a) wedges (b, w), w over row a in order.
+    The wedges are expanded exactly, with no padding, and cut into chunks
+    of at most ``budget``, so a boundary may fall inside a row.  A wedge
+    closes a triangle iff the key b * n + w is a CSR entry's key; one
+    ``searchsorted`` of a chunk's keys into the graph's sorted keys finds
+    every member.  Yields (e_ab, e_aw, e_bw), the edge ids of the hits,
+    ordered by the position of e_ab in ``eids``, then by w.
     """
-    a = g.src[eids].astype(np.int64)
-    b = g.dst[eids].astype(np.int64)
-    C = len(a)
-    if C == 0 or D == 0:
-        z = np.zeros(0, np.int64)
-        return z, z, z, np.zeros(0, bool)
-    slot = np.arange(D, dtype=np.int64)[None, :]
-    row_start = g.indptr[a].astype(np.int64)[:, None]
-    row_len = (g.indptr[a + 1] - g.indptr[a]).astype(np.int64)[:, None]
-    valid = slot < row_len
-    pos_aw = np.minimum(row_start + slot, max(len(g.nbrs) - 1, 0))
-    w = g.nbrs[pos_aw].astype(np.int64)
-    # binary search w in row b
-    lo = np.broadcast_to(g.indptr[b].astype(np.int64)[:, None], (C, D))
-    hi = np.broadcast_to(g.indptr[b + 1].astype(np.int64)[:, None], (C, D))
-    iters = _search_iters(g.max_out_deg)
-    p = _row_lower_bound_np(g.nbrs, lo.reshape(-1), hi.reshape(-1), w.reshape(-1), iters)
-    p = p.reshape(C, D)
-    in_row = p < g.indptr[b + 1].astype(np.int64)[:, None]
-    pc = np.minimum(p, max(len(g.nbrs) - 1, 0))
-    hit = valid & in_row & (g.nbrs[pc] == w)
-    eid = np.broadcast_to(eids[:, None], (C, D))
-    e_aw = g.nbr_eid[pos_aw].astype(np.int64)
-    e_bw = g.nbr_eid[pc].astype(np.int64)
-    f = hit.reshape(-1)
-    return eid.reshape(-1)[f], e_aw.reshape(-1)[f], e_bw.reshape(-1)[f], f
+    eids = np.asarray(eids, dtype=np.int64)
+    if len(eids) == 0:
+        return
+    keys = csr_keys(g.indptr, g.nbrs, g.n)
+    nbrs, nbr_eid = g.nbrs, g.nbr_eid
+    indptr = g.indptr.astype(np.int64)
+    a = g.src[eids]
+    row_lo = indptr[a]
+    n_wedges = indptr[a + 1] - row_lo
+    ends = np.cumsum(n_wedges)
+    starts = ends - n_wedges
+    # global wedge k of edge i sits at CSR position k + shift[i] of row a
+    shift = row_lo - starts
+    row_b = g.dst[eids].astype(np.int64) * np.int64(g.n)
+    last = len(keys) - 1
+    total = int(ends[-1])
+    for k_lo in range(0, total, budget):
+        k_hi = min(k_lo + budget, total)
+        # edges [i_lo, i_hi) own the chunk's wedges, the outer two in part
+        i_lo = int(np.searchsorted(ends, k_lo, side="right"))
+        i_hi = int(np.searchsorted(starts, k_hi, side="left"))
+        take = (np.minimum(ends[i_lo:i_hi], k_hi)
+                - np.maximum(starts[i_lo:i_hi], k_lo))
+        pos_aw = np.repeat(shift[i_lo:i_hi], take)
+        pos_aw += np.arange(k_lo, k_hi, dtype=np.int64)
+        q = np.repeat(row_b[i_lo:i_hi], take)
+        q += nbrs[pos_aw]
+        p = np.searchsorted(keys, q)
+        np.minimum(p, last, out=p)
+        hit = np.flatnonzero(keys[p] == q)
+        yield (np.repeat(eids[i_lo:i_hi], take)[hit], nbr_eid[pos_aw[hit]],
+               nbr_eid[p[hit]])
 
 
-def edge_support_np(g: Graph, chunk: int = 1 << 16) -> np.ndarray:
-    """Support of every canonical edge (numpy, chunked)."""
+def _triangle_rows(g: Graph, eids: np.ndarray, budget: int) -> np.ndarray:
+    """(T, 3) int32 rows (e_ab, e_aw, e_bw) of every triangle ``eids`` close."""
+    out = [np.stack(hits, axis=1) for hits in _wedge_hits(g, eids, budget)]
+    return (np.concatenate(out, axis=0).astype(np.int32) if out
+            else np.zeros((0, 3), np.int32))
+
+
+def edge_support_np(g: Graph, budget: int = WEDGE_BUDGET) -> np.ndarray:
+    """Support of every canonical edge (numpy, chunked by wedge count)."""
     sup = np.zeros(g.m, dtype=np.int64)
     with span("edge_support", m=g.m):
-        for e_lo in range(0, g.m, chunk):
-            e_hi = min(e_lo + chunk, g.m)
-            e_ab, e_aw, e_bw, _ = _wedge_hits_np(g, e_lo, e_hi)
-            np.add.at(sup, e_ab, 1)
-            np.add.at(sup, e_aw, 1)
-            np.add.at(sup, e_bw, 1)
+        for hits in _wedge_hits(g, np.arange(g.m), budget):
+            sup += np.bincount(np.concatenate(hits), minlength=g.m)
     return sup
 
 
-def list_triangles_np(g: Graph, chunk: int = 1 << 16) -> np.ndarray:
-    """Static triangle list: (T, 3) int32 edge-id triples, each triangle once."""
+def list_triangles_np(g: Graph, budget: int = WEDGE_BUDGET) -> np.ndarray:
+    """Static triangle list: (T, 3) int32 edge-id triples, each triangle
+    once, enumerated in edge-id order."""
     with span("list_triangles") as sp:
-        out = []
-        for e_lo in range(0, g.m, chunk):
-            e_hi = min(e_lo + chunk, g.m)
-            e_ab, e_aw, e_bw, _ = _wedge_hits_np(g, e_lo, e_hi)
-            out.append(np.stack([e_ab, e_aw, e_bw], axis=1))
-        tris = (np.concatenate(out, axis=0).astype(np.int32) if out
-                else np.zeros((0, 3), np.int32))
+        tris = _triangle_rows(g, np.arange(g.m), budget)
         sp.count(triangles=len(tris))
     return tris
 
 
-def list_triangles(
-    g: Graph, chunk: int = 1 << 14, budget: int = 1 << 18
-) -> np.ndarray:
-    """Skew-aware triangle listing (host path of DESIGN.md §4).
+def list_triangles(g: Graph, budget: int = WEDGE_BUDGET) -> np.ndarray:
+    """Triangle listing in the device path's bucket order (DESIGN.md §4).
 
-    ``list_triangles_np`` materializes a (chunk, max_out_deg) wedge tensor,
-    so one hub row inflates every chunk on power-law graphs.  This variant
-    reuses ``wedge_bucket_plan``: oriented edges are grouped by the pow2
-    out-degree of their source row and each bucket enumerates with its own
-    ``D``, keeping the materialized wedge area at Σ_b C_b·D_b instead of
-    m·D_max.  Same triangles (each exactly once), different row order.
+    The same triangles as ``list_triangles_np``, each exactly once, with
+    the edges enumerated as ``wedge_bucket_plan`` groups them: by the pow2
+    out-degree of their source row, ids ascending within a bucket.
     """
     with span("list_triangles") as sp:
-        out = []
-        for bucket in wedge_bucket_plan(g, chunk, budget):
-            ids = bucket.eids[: bucket.n_real].astype(np.int64)
-            for lo in range(0, len(ids), bucket.chunk):
-                e_ab, e_aw, e_bw, _ = _wedge_hits_ids_np(
-                    g, ids[lo : lo + bucket.chunk], bucket.D)
-                if len(e_ab):
-                    out.append(np.stack([e_ab, e_aw, e_bw], axis=1))
-        tris = (np.concatenate(out, axis=0).astype(np.int32) if out
-                else np.zeros((0, 3), np.int32))
+        plan = wedge_bucket_plan(g)
+        eids = (np.concatenate([b.eids[: b.n_real] for b in plan]) if plan
+                else np.zeros(0, np.int64))
+        tris = _triangle_rows(g, eids, budget)
         sp.count(triangles=len(tris))
     return tris
 
@@ -224,6 +210,10 @@ def triangle_density(m: int, n_tris: int) -> float:
 # ---------------------------------------------------------------------------
 # JAX path
 # ---------------------------------------------------------------------------
+
+def _search_iters(max_row: int) -> int:
+    return max(1, math.ceil(math.log2(max_row + 1))) if max_row > 0 else 1
+
 
 def _row_lower_bound_jax(nbrs, lo, hi, target, iters):
     n_entries = nbrs.shape[0]
