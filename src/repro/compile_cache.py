@@ -1,11 +1,11 @@
 """JAX's persistent compilation cache, at one fixed place per checkout.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call
-:func:`enable_compile_cache` once before their first compile; the library
-never does, so importing it changes no JAX setting.  Where
-``JAX_COMPILATION_CACHE_DIR`` is set the cache lives there and nowhere
-else; otherwise it lives in ``<repo>/.jax_cache``.  The path must not move
-between runs: it is part of what a cached entry is found by.
+An entry point (``chip_smoke.py``) calls :func:`enable_compile_cache`
+once before its first compile; the library never does, so importing it
+changes no JAX setting.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the
+cache lives there and nowhere else; otherwise it lives in
+``<repo>/.jax_cache``.  The path must not move between runs: it is part
+of what a cached entry is found by.
 """
 
 from __future__ import annotations

@@ -15,29 +15,23 @@ k: extract the candidate subgraph H = NS(U_k), U_k = endpoints of edges with
 Φ_k (Theorem 2); delete them from G_new and continue.  Empty classes are
 skipped by jumping k straight to ``min lb`` over the remaining edges.
 
-Engines (DESIGN.md §8):
-
-* ``engine="batched"`` (default) — one :class:`partition.PartitionBatch` per
-  round: every NS(P) compacted to local ids, parts grouped into pow4 size
-  classes, lane-packed and padded to static shapes, every bucket decomposed
-  in ONE device call (``peel.peel_classes_batched``, one compile per bucket
-  shape); the
-  working graph shrinks via ``Graph.remove_edges`` incremental maintenance
-  instead of a per-round rebuild.  Rounds are **double-buffered**
-  (DESIGN.md §9): a round's internal-edge removal is known at batch-build
-  time, so the ``_partition_rounds`` producer advances the working graph
-  and builds round r + 1 on the host while the device still peels round r
-  (non-blocking dispatch, results consumed one round late).  Stage-2
-  candidates are compacted and peeled on pow4-padded shapes
-  (``peel.local_threshold_peel``), so consecutive k values share one
-  compiled kernel — and are **pipelined** the same way the stage-1 rounds
-  are (DESIGN.md §11): level k+1's candidate is pre-built on the host from
-  the pre-result masks (a superset U′ ⊇ U_{k+1}, provably sound) while the
-  device peels level k; the edges level k removes are killed at use time
-  via the peel's ``alive0`` mask (``OocStats.stage2_overlapped``).
-* ``engine="perpart"`` — the seed path (full ``build_graph`` per round, one
-  host triangle enumeration and one freshly-shaped device peel per part);
-  kept as the before/after benchmark baseline (BENCH_ooc.json).
+The engine (DESIGN.md §8): one :class:`partition.PartitionBatch` per
+round: every NS(P) compacted to local ids, parts grouped into pow4 size
+classes, lane-packed and padded to static shapes, every bucket decomposed
+in ONE device call (``peel.peel_classes_batched``, one compile per bucket
+shape); the working graph shrinks via ``Graph.remove_edges`` incremental
+maintenance instead of a per-round rebuild.  Rounds are **double-buffered**
+(DESIGN.md §9): a round's internal-edge removal is known at batch-build
+time, so the ``_partition_rounds`` producer advances the working graph
+and builds round r + 1 on the host while the device still peels round r
+(non-blocking dispatch, results consumed one round late).  Stage-2
+candidates are compacted and peeled on pow4-padded shapes
+(``peel.local_threshold_peel``), so consecutive k values share one
+compiled kernel — and are **pipelined** the same way the stage-1 rounds
+are (DESIGN.md §11): level k+1's candidate is pre-built on the host from
+the pre-result masks (a superset U′ ⊇ U_{k+1}, provably sound) while the
+device peels level k; the edges level k removes are killed at use time
+via the peel's ``alive0`` mask (``OocStats.stage2_overlapped``).
 
 Deviation from the paper (documented in DESIGN.md §7): Algorithm 3 Step 8
 flags internal zero-support edges as Φ_2 in *every* round, but from round 2
@@ -59,7 +53,6 @@ import time
 import warnings
 from typing import Callable, Dict, Iterator, List, Optional, Tuple, Union
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import manager as ckpt
@@ -68,12 +61,10 @@ from repro.core import graph as glib
 from repro.core import partition as plib
 from repro.core.store import GraphStore
 from repro.core.peel import (_mesh_axes, _mesh_size, local_threshold_peel,
-                             peel_classes, peel_classes_batched,
-                             peel_threshold)
+                             peel_classes_batched)
 from repro.core.spans import span
 from repro.core import support as sup_lib
-from repro.core.support import (list_triangles, list_triangles_np,
-                                support_from_triangle_list)
+from repro.core.support import list_triangles, support_from_triangle_list
 
 # The degradation ladder's floor for the per-round working-set budget:
 # halving below this cannot meaningfully shrink a dispatch (a single lane
@@ -227,10 +218,9 @@ class OocStats:
     """Work counters of one out-of-core run (mirrors ``PeelStats``).
 
     ``compiles`` counts distinct padded shapes this run traced — the cost
-    the bucket padding exists to bound (the seed per-part path compiled once
-    per part shape).  The jit cache is process-global, so the counter is an
-    upper bound on actual XLA work.  ``padding_waste`` is the fraction of
-    materialized lane slots that held no real edge.
+    the bucket padding exists to bound.  The jit cache is process-global,
+    so the counter is an upper bound on actual XLA work.  ``padding_waste``
+    is the fraction of materialized lane slots that held no real edge.
     """
 
     rounds: int = 0           # partition rounds (the paper's O(m/M) scans)
@@ -332,7 +322,7 @@ class OocStats:
         estimate vs the actual per-round enumerations (DESIGN.md §11).
         The cost model only steers locality — a wildly wrong estimate can
         cost rounds, never correctness — but the error is surfaced so the
-        estimator's drift on new graph shapes is visible in benchmarks.
+        estimator's drift on new graph shapes is visible in the stats.
         The denominator floors at 1 so triangle-free runs still expose an
         over-predicting estimator instead of reporting it as exact."""
         return abs(self.tri_est - self.tri_total) / max(self.tri_total, 1)
@@ -537,31 +527,11 @@ class RoundJournal:
         return tree, meta
 
 
-def _local_truss(sub_edges: np.ndarray, n: int) -> np.ndarray:
-    """Trussness of every edge of the subgraph (seed per-part local peel).
-
-    One ``build_graph`` over the FULL vertex space, one host triangle
-    enumeration and one dynamically-shaped device peel per call — the
-    per-part cost model the batched engine replaces; kept as the benchmark
-    baseline and as a second implementation for the batch-padding tests.
-    """
-    g = glib.build_graph(n, sub_edges)
-    if g.m == 0:
-        return np.zeros(0, np.int64)
-    tris = list_triangles_np(g)
-    sup = support_from_triangle_list(tris, g.m).astype(np.int32)
-    if len(tris) == 0:
-        tris = np.full((1, 3), g.m, np.int32)
-    phi, _ = peel_classes(jnp.asarray(sup), jnp.asarray(tris), jnp.ones(g.m, bool))
-    return np.asarray(phi).astype(np.int64)
-
-
 def lower_bounding(
     n: int,
     edges: np.ndarray,
     budget: int,
     partitioner: str | Callable = "sequential",
-    engine: str = "batched",
     *,
     partitioner_seed: int = 0,
     mesh=None,
@@ -576,7 +546,7 @@ def lower_bounding(
     """Algorithm 3: per-edge lower bounds + exact round-1 Phi_2.
 
     With a ``mesh``, every round's bucket peels span the mesh axis
-    (DESIGN.md §10); requires the batched engine.  ``mesh_axis`` may be a
+    (DESIGN.md §10).  ``mesh_axis`` may be a
     single axis name or a ``(lane, tri)`` tuple for multi-axis meshes
     (DESIGN.md §13); ``kernel`` routes each lane's peel engine
     (``"pallas" | "xla" | "auto"``, forwarded to
@@ -589,11 +559,9 @@ def lower_bounding(
     from it, and ``max_retries`` bounds the lane-split retries a failed
     dispatch gets before the engine degrades.  ``engine_state`` shares one
     mutable :class:`_Engine` with the caller so a mesh drop here carries
-    into stage 2.  Both engines compute identical bounds, but only the
-    batched engine journals — its per-round state lives in flat host
-    arrays; the per-part seed path is the benchmark baseline.
+    into stage 2.
 
-    ``store`` (batched engine only) routes the round loop's working graph
+    ``store`` routes the round loop's working graph
     through a :class:`~repro.core.store.GraphStore` — with a
     ``ChunkedDiskStore`` the graph lives on disk between rounds and the
     store's prefetch thread overlaps the chunk reads with the device peel
@@ -601,20 +569,6 @@ def lower_bounding(
     """
     part_fn = _resolve_partitioner(partitioner, seed=partitioner_seed)
     edges = glib.canonical_edges(edges, n)
-    if engine == "perpart":
-        if mesh is not None:
-            raise ValueError("mesh= requires the batched engine")
-        if journal is not None or restored is not None:
-            raise ValueError(
-                "checkpointing requires the batched engine "
-                "(engine='perpart' is the uninstrumented seed baseline)")
-        if store is not None:
-            raise ValueError(
-                "store= requires the batched engine "
-                "(engine='perpart' is the uninstrumented seed baseline)")
-        return _lower_bounding_perpart(n, edges, budget, part_fn)
-    if engine != "batched":
-        raise ValueError(f"unknown engine {engine!r}")
     return _lower_bounding_batched(n, edges, budget, part_fn,
                                    mesh=mesh, mesh_axis=mesh_axis,
                                    kernel=kernel,
@@ -1014,56 +968,6 @@ def _lower_bounding_batched(n, edges, budget, part_fn, mesh=None,
     )
 
 
-def _lower_bounding_perpart(n, edges, budget, part_fn) -> LowerBoundResult:
-    """Seed path: per-round rebuild, per-part NS scan + dynamic-shape peel."""
-    m = len(edges)
-    phi = np.zeros(m, dtype=np.int64)
-    lb = np.full(m, 2, dtype=np.int64)
-    alive = np.ones(m, dtype=bool)          # still in the working graph
-    in_gnew = np.zeros(m, dtype=bool)       # emitted to G_new
-    stats = OocStats()
-    cur_budget = budget
-
-    while alive.any():
-        stats.rounds += 1
-        cur_ids = np.nonzero(alive)[0]
-        g = glib.build_graph(n, edges[cur_ids])
-        parts = part_fn(g, cur_budget, stats.rounds)
-        if not parts:
-            break
-        round_removed = np.zeros(len(cur_ids), dtype=bool)
-        for P in parts:
-            stats.scans += 1
-            stats.parts += 1
-            stats.batches += 1
-            sub_ids, sub_edges, internal = glib.neighborhood_subgraph(g, P)
-            if len(sub_ids) == 0:
-                continue
-            stats.max_part_edges = max(stats.max_part_edges, len(sub_ids))
-            stats.real_edges += len(sub_ids)
-            stats.padded_slots += len(sub_ids)
-            phi_local = _local_truss(sub_edges, n)
-            int_ids = sub_ids[internal]               # ids in current graph
-            glob_ids = cur_ids[int_ids]               # ids in original graph
-            lb[glob_ids] = np.maximum(lb[glob_ids], phi_local[internal])
-            if stats.rounds == 1:
-                is_phi2 = phi_local[internal] == 2
-                phi[glob_ids[is_phi2]] = 2
-                in_gnew[glob_ids[~is_phi2]] = True
-            else:
-                in_gnew[glob_ids] = True
-            round_removed[int_ids] = True
-        if not round_removed.any():
-            cur_budget *= 2
-            continue
-        alive[cur_ids[round_removed]] = False
-
-    return LowerBoundResult(
-        edges=edges, phi=phi, lb=lb, in_gnew=in_gnew, rounds=stats.rounds,
-        scans=stats.scans, max_part_edges=stats.max_part_edges, stats=stats,
-    )
-
-
 @dataclasses.dataclass
 class BottomUpResult:
     edges: np.ndarray
@@ -1111,7 +1015,6 @@ def bottom_up_decompose(
     edges: np.ndarray,
     budget: int,
     partitioner: str | Callable = "sequential",
-    engine: str = "batched",
     *,
     partitioner_seed: int = 0,
     mesh=None,
@@ -1126,10 +1029,10 @@ def bottom_up_decompose(
 ) -> BottomUpResult:
     """Algorithm 4: full decomposition under a working-set budget.
 
-    With a ``mesh`` (batched engine only), stage-1 rounds split their
-    bucket lanes over ``mesh_axis`` and stage-2 candidate peels run
-    triangle-sharded — one partition round spans the pod (DESIGN.md §10);
-    ``OocStats.devices`` / ``sharded_rounds`` record the routing.  A
+    With a ``mesh``, stage-1 rounds split their bucket lanes over
+    ``mesh_axis`` and stage-2 candidate peels run triangle-sharded — one
+    partition round spans the pod (DESIGN.md §10); ``OocStats.devices`` /
+    ``sharded_rounds`` record the routing.  A
     ``(lane, tri)`` tuple ``mesh_axis`` additionally shards each lane's
     triangle sweep over the second axis (DESIGN.md §13).  ``kernel``
     routes the per-lane peel engine (``"pallas" | "xla" | "auto"``);
@@ -1151,24 +1054,16 @@ def bottom_up_decompose(
     (mesh drop, then budget halving); ``OocStats.retries / degraded /
     checkpoints / resumed_round`` record all of it.
 
-    ``store`` (batched engine only) runs stage 1's working graph through a
+    ``store`` runs stage 1's working graph through a
     :class:`~repro.core.store.GraphStore` (DESIGN.md §15); the store's I/O
     counters land in ``OocStats``.  Neither the store nor
     ``checkpoint_every``'s gating mode enters the run key — they change
     I/O behavior, never φ or the round trajectory, so a crashed disk-backed
     run may resume in-memory and vice versa.
     """
-    if store is not None and engine != "batched":
-        raise ValueError(
-            "store= requires the batched engine "
-            "(engine='perpart' is the uninstrumented seed baseline)")
     journal = None
     snap = None
     if checkpoint_dir is not None:
-        if engine != "batched":
-            raise ValueError(
-                "checkpointing requires the batched engine "
-                "(engine='perpart' is the uninstrumented seed baseline)")
         edges = glib.canonical_edges(edges, n)
         key = _run_key("bottom_up", n, edges, budget, partitioner,
                        partitioner_seed,
@@ -1195,7 +1090,7 @@ def bottom_up_decompose(
     else:
         k0 = 2
         lbres = lower_bounding(
-            n, edges, budget, partitioner, engine=engine,
+            n, edges, budget, partitioner,
             partitioner_seed=partitioner_seed, mesh=mesh,
             mesh_axis=mesh_axis, journal=journal,
             restored=snap if snap is not None
@@ -1211,7 +1106,7 @@ def bottom_up_decompose(
 
     def candidate_masks(k_b: int):
         """U_k and NS(U_k) from the CURRENT ``remaining`` mask — the one
-        extraction both engines share.  Returns ``(h_ids, internal)`` or
+        extraction of a candidate level.  Returns ``(h_ids, internal)`` or
         None when no remaining edge admits class k_b."""
         elig = remaining & (lb <= k_b)
         if not elig.any():
@@ -1264,81 +1159,63 @@ def bottom_up_decompose(
         # jump k straight there instead of probing one k at a time.
         k = max(k, int(lb[remaining].min()))
         stats.scans += 1
-        if engine == "perpart":
-            # seed path: blocking per-level extraction + full-shape peel
-            # (non-empty by the k-jump above)
-            h_ids, internal = candidate_masks(k)
-            cand_sizes.append(len(h_ids))
-            sub = glib.build_graph(n, edges[h_ids])
-            tris = list_triangles_np(sub)
-            sup = support_from_triangle_list(tris, sub.m).astype(np.int32)
-            if len(tris) == 0:
-                tris = np.full((1, 3), sub.m, np.int32)
-            # Map internal mask to subgraph ids (canonical order preserved).
-            removable = jnp.asarray(internal[h_ids])
-            _, _, removed = peel_threshold(
-                jnp.asarray(sup), jnp.asarray(tris),
-                jnp.ones(sub.m, bool), removable, jnp.int32(k - 2),
-            )
-            removed = np.asarray(removed)
+        if pre is not None and pre[0] == k:
+            cand = pre           # built while level k-1 was peeling
+            stats.stage2_overlapped += 1
         else:
-            if pre is not None and pre[0] == k:
-                cand = pre           # built while level k-1 was peeling
-                stats.stage2_overlapped += 1
-            else:
-                cand = build_candidate(k)
-            pre = None
-            _, h_ids, tris, internal = cand
-            cand_sizes.append(len(h_ids))
-            # kill the edges the previous level removed after this
-            # candidate was built; supports count fully-alive triangles
-            alive_h = remaining[h_ids]
-            if len(tris):
-                t_alive = (alive_h[tris[:, 0]] & alive_h[tris[:, 1]]
-                           & alive_h[tris[:, 2]])
-                sup = support_from_triangle_list(
-                    tris[t_alive], len(h_ids)).astype(np.int32)
-            else:
-                sup = np.zeros(len(h_ids), np.int32)
-            handle = dispatch_exc = None
-            try:
-                handle = local_threshold_peel(
-                    sup, tris, internal[h_ids], k - 2, alive0=alive_h,
-                    shape_cache=shape_cache, blocking=False, mesh=eng.mesh,
-                    mesh_axis=eng.mesh_axis, kernel=eng.kernel,
-                    fault_ctx={"stage": 2, "k": int(k), "retry": 0})
-                stats.compiles += int(handle.new_compile)
-                stats.count_lanes(handle)
+            cand = build_candidate(k)
+        pre = None
+        _, h_ids, tris, internal = cand
+        cand_sizes.append(len(h_ids))
+        # kill the edges the previous level removed after this
+        # candidate was built; supports count fully-alive triangles
+        alive_h = remaining[h_ids]
+        if len(tris):
+            t_alive = (alive_h[tris[:, 0]] & alive_h[tris[:, 1]]
+                       & alive_h[tris[:, 2]])
+            sup = support_from_triangle_list(
+                tris[t_alive], len(h_ids)).astype(np.int32)
+        else:
+            sup = np.zeros(len(h_ids), np.int32)
+        handle = dispatch_exc = None
+        try:
+            handle = local_threshold_peel(
+                sup, tris, internal[h_ids], k - 2, alive0=alive_h,
+                shape_cache=shape_cache, blocking=False, mesh=eng.mesh,
+                mesh_axis=eng.mesh_axis, kernel=eng.kernel,
+                fault_ctx={"stage": 2, "k": int(k), "retry": 0})
+            stats.compiles += int(handle.new_compile)
+            stats.count_lanes(handle)
+            stats.batches += 1
+            stats.sharded_rounds += int(handle.sharded)
+        except Exception as exc:
+            dispatch_exc = exc      # enters the retry ladder below
+        # pipeline: extract + compact level k+1's candidate on the host
+        # while the device peels level k (DESIGN.md §11)
+        pre = build_candidate(k + 1)
+        try:
+            if dispatch_exc is not None:
+                raise dispatch_exc
+            _, removed = handle.result()
+        except Exception as exc:
+            # the level's host inputs survive the donation: re-dispatch
+            # through the retry ladder (DESIGN.md §12)
+            def redispatch(retry, e, _k=k, _sup=sup, _tris=tris,
+                           _rm=internal[h_ids], _alive=alive_h):
+                h = local_threshold_peel(
+                    _sup, _tris, _rm, _k - 2, alive0=_alive,
+                    shape_cache=shape_cache, blocking=False,
+                    mesh=e.mesh, mesh_axis=e.mesh_axis, kernel=e.kernel,
+                    fault_ctx={"stage": 2, "k": int(_k),
+                               "retry": retry})
+                stats.compiles += int(h.new_compile)
+                stats.count_lanes(h)
                 stats.batches += 1
-                stats.sharded_rounds += int(handle.sharded)
-            except Exception as exc:
-                dispatch_exc = exc      # enters the retry ladder below
-            # pipeline: extract + compact level k+1's candidate on the host
-            # while the device peels level k (DESIGN.md §11)
-            pre = build_candidate(k + 1)
-            try:
-                if dispatch_exc is not None:
-                    raise dispatch_exc
-                _, removed = handle.result()
-            except Exception as exc:
-                # the level's host inputs survive the donation: re-dispatch
-                # through the retry ladder (DESIGN.md §12)
-                def redispatch(retry, e, _k=k, _sup=sup, _tris=tris,
-                               _rm=internal[h_ids], _alive=alive_h):
-                    h = local_threshold_peel(
-                        _sup, _tris, _rm, _k - 2, alive0=_alive,
-                        shape_cache=shape_cache, blocking=False,
-                        mesh=e.mesh, mesh_axis=e.mesh_axis, kernel=e.kernel,
-                        fault_ctx={"stage": 2, "k": int(_k),
-                                   "retry": retry})
-                    stats.compiles += int(h.new_compile)
-                    stats.count_lanes(h)
-                    stats.batches += 1
-                    _, rem = h.result()
-                    return rem
+                _, rem = h.result()
+                return rem
 
-                removed = _retry_candidate_peel(eng, stats, exc, redispatch,
-                                                max_retries, stage="s2")
+            removed = _retry_candidate_peel(eng, stats, exc, redispatch,
+                                            max_retries, stage="s2")
         rm_glob = h_ids[removed]
         phi[rm_glob] = k
         remaining[rm_glob] = False
@@ -1447,7 +1324,6 @@ def partitioned_support(
     edges: np.ndarray,
     budget: int,
     partitioner: str | Callable = "sequential",
-    engine: str = "batched",
     with_stats: bool = False,
     *,
     partitioner_seed: int = 0,
@@ -1468,14 +1344,14 @@ def partitioned_support(
     a triangle's three vertices, and a triangle loses an edge from the
     working graph the moment it is first credited).
 
-    The batched engine lists each NS(P)'s triangles through the compacted,
+    Each NS(P)'s triangles are listed through the compacted,
     skew-aware machinery and credits them in one vectorized scatter per
     bucket; no peeling is involved, so the batch is built without incidence
     and a ``mesh`` only records ``OocStats.devices`` for the caller
     (top-down threads it here so one stats object describes both stages —
     the credit scatters themselves are host-side and never span the mesh).
 
-    ``journal`` / ``restored`` (batched engine only) snapshot the credit
+    ``journal`` / ``restored`` snapshot the credit
     state after each completed round as ``"sup"``-stage snapshots and
     resume from one (DESIGN.md §12): the exactly-once crediting invariant
     is per-working-graph, so restarting the rounds from the journaled
@@ -1488,7 +1364,7 @@ def partitioned_support(
     idempotent, a round's triples are all computed before any is folded,
     so a mid-round failure never half-credits.  ``max_retries`` bounds the
     lane-split rungs; ``store`` routes the working graph through a
-    :class:`~repro.core.store.GraphStore` (batched engine only).
+    :class:`~repro.core.store.GraphStore`.
     """
     part_fn = _resolve_partitioner(partitioner, seed=partitioner_seed)
     edges = glib.canonical_edges(edges, n)
@@ -1497,19 +1373,9 @@ def partitioned_support(
     alive = np.ones(m, dtype=bool)
     stats = OocStats()
     if mesh is not None:
-        if engine == "perpart":
-            raise ValueError("mesh= requires the batched engine")
         stats.devices = _mesh_devices(mesh, mesh_axis)
-    if store is not None and engine == "perpart":
-        raise ValueError(
-            "store= requires the batched engine "
-            "(engine='perpart' is the uninstrumented seed baseline)")
     cur_budget = budget
     if restored is not None:
-        if engine == "perpart":
-            raise ValueError(
-                "checkpointing requires the batched engine "
-                "(engine='perpart' is the uninstrumented seed baseline)")
         tree, meta = restored
         sup = tree["sup"].astype(np.int64)
         alive = tree["alive"].astype(bool)
@@ -1519,37 +1385,6 @@ def partitioned_support(
         stats.devices = dev
         cur_budget = int(meta.get("cur_budget", budget))
         _restore_zone_state(part_fn, meta.get("zone_state"))
-
-    if engine == "perpart":
-        alive = np.ones(m, dtype=bool)
-        while alive.any():
-            stats.rounds += 1
-            cur_ids = np.nonzero(alive)[0]
-            g = glib.build_graph(n, edges[cur_ids])
-            parts = part_fn(g, cur_budget, stats.rounds)
-            if not parts:
-                break
-            round_removed = np.zeros(len(cur_ids), dtype=bool)
-            for P in parts:
-                stats.scans += 1
-                sub_ids, sub_edges, internal = glib.neighborhood_subgraph(g, P)
-                if len(sub_ids) == 0:
-                    continue
-                sub = glib.build_graph(n, sub_edges)
-                tris = list_triangles_np(sub)
-                if len(tris):
-                    # subgraph edge id -> current-graph id -> original id
-                    to_glob = cur_ids[sub_ids]
-                    np.add.at(sup, to_glob[tris.reshape(-1)], 1)
-                round_removed[sub_ids[internal]] = True
-            if not round_removed.any():
-                cur_budget *= 2   # stall fallback (see lower_bounding)
-                continue
-            alive[cur_ids[round_removed]] = False
-        return (sup, stats) if with_stats else sup
-
-    if engine != "batched":
-        raise ValueError(f"unknown engine {engine!r}")
 
     # The triangle-credit counter is all host-side scatters (no device
     # peel), so the shared round generator is consumed directly — same

@@ -580,7 +580,8 @@ def incident_vertices(edges: np.ndarray) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Reference statistics used by benchmarks (Table 6).
+# Graph statistics for the paper's Table 6 comparison
+# (examples/truss_analysis.py).
 # ---------------------------------------------------------------------------
 
 def clustering_coefficient(n: int, edges: np.ndarray) -> float:

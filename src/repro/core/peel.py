@@ -34,14 +34,9 @@ default ``cap_t`` already covers the largest row, so this is a safety
 valve, not a steady-state path).
 
 State is fixed-shape; each kernel invocation is one ``lax.while_loop`` —
-jit-compatible, vmap-compatible (``distributed_local_truss``) and
-shard_map-compatible (``peel_classes_sharded`` adds a ``pmin`` on the chunk
-prefix and a ``psum`` on the decrements).
-
-``peel_recompute`` is the *global-iterate* baseline standing in for the
-MapReduce algorithm [16]: no incremental bookkeeping — every round recounts
-all supports from scratch (the algorithmic reason TD-MR loses by orders of
-magnitude in the paper's Table 4).
+jit-compatible, vmap-compatible (the batched out-of-core lanes) and
+shard_map-compatible (the ``distributed`` mesh programs add a ``pmin`` on
+the chunk prefix and a ``psum`` on the decrements).
 """
 
 from __future__ import annotations
@@ -308,9 +303,9 @@ def peel_threshold_fixedcap(sup0, tris, tri_indptr, tri_ids, alive0,
 def _default_caps(m: int, incidence, cap_f, cap_t):
     """Capacity heuristic: large rounds are chunked anyway, so capacities
     trade static per-round gather width against extra sub-rounds.  The
-    m//48 and 3T//96 divisors came out of a sweep on the power-law benchmark
-    graphs (BENCH_peel.json); the floor on ``cap_t`` must cover the largest
-    single incidence row or progress could stall."""
+    m//48 and 3T//96 divisors came out of a CPU sweep on power-law graphs
+    and have not been set on the chip; the floor on ``cap_t`` must cover
+    the largest single incidence row or progress could stall."""
     indptr, tri_ids = incidence
     max_row = int((indptr[1:] - indptr[:-1]).max()) if m else 0
     n_inc = len(tri_ids)
@@ -916,15 +911,15 @@ def local_threshold_peel(sup0, tris, removable, thresh, *, alive0=None,
 
 
 # ---------------------------------------------------------------------------
-# dense (seed) engine — O(T) scatter work per round; baseline + oracle
+# dense (seed) engine — O(T) scatter work per round
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("max_k",))
 def peel_classes_dense(sup0, tris, edge_alive0, max_k=None):
     """Seed bulk peel: every round rescans the full triangle list.
 
-    Kept as the before/after benchmark baseline for the frontier engine and
-    as a second independent implementation for cross-checks.
+    ``engine="auto"`` takes it for triangle-poor graphs (``_pick_engine``),
+    and it is a second independent implementation for cross-checks.
     """
     m = sup0.shape[0]
     phi0 = jnp.zeros(m, jnp.int32)
@@ -989,47 +984,6 @@ def peel_threshold_dense(sup0, tris, alive0, removable, thresh):
     return alive, sup, alive0 & ~alive
 
 
-@partial(jax.jit, static_argnames=("m",))
-def support_from_triangles(tris, alive, m):
-    """sup(e) = number of fully-alive triangles containing e."""
-    ta = _tri_alive(alive, tris).astype(jnp.int32)
-    sup = jnp.zeros(m + 1, jnp.int32)
-    for c in range(3):
-        sup = sup.at[tris[:, c]].add(ta, mode="drop")
-    return sup[:m]
-
-
-@jax.jit
-def peel_recompute(tris, edge_alive0):
-    """Global-iterate baseline (MapReduce [16] stand-in): each round recounts
-    every support from scratch, removes all violating edges, repeats.
-
-    Deliberately NOT frontier-compacted — its O(T)-every-round recount is the
-    algorithmic property the paper's Table 4 comparison measures.
-    """
-    m = edge_alive0.shape[0]
-    phi0 = jnp.zeros(m, jnp.int32)
-    k0 = jnp.int32(2)
-
-    def cond(state):
-        alive, phi, k = state
-        return jnp.any(alive)
-
-    def body(state):
-        alive, phi, k = state
-        sup = support_from_triangles(tris, alive, m)
-        rm = alive & (sup <= k - 2)
-        has_rm = jnp.any(rm)
-        min_sup = jnp.min(jnp.where(alive, sup, _BIG))
-        new_k = jnp.where(has_rm, k, jnp.maximum(k + 1, min_sup + 2))
-        phi = jnp.where(rm, k, phi)
-        alive = alive & ~rm
-        return alive, phi, new_k
-
-    alive, phi, k = jax.lax.while_loop(cond, body, (edge_alive0, phi0, k0))
-    return phi
-
-
 def estimate_working_set(g) -> int:
     """In-memory peel working set, in int32 entries (dispatch heuristic).
 
@@ -1071,7 +1025,7 @@ def truss_decompose(n: int, edges: np.ndarray, *, engine: str = "auto",
     ``mesh``: span each out-of-core partition round across the mesh
     (DESIGN.md §10) — bucket lanes split over ``mesh_axis``, per-k candidate
     peels triangle-sharded.  The in-memory engines are single-program and
-    ignore it (``distributed.peel_classes_sharded`` is their mesh form).
+    ignore it.
     ``mesh_axes`` (a sequence of axis names) overrides ``mesh_axis`` with a
     MULTI-AXIS layout (DESIGN.md §13): bucket lanes split over the first
     axis while each lane's triangles shard over the second, and candidate

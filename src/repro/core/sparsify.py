@@ -1,14 +1,10 @@
-"""Truss-based graph utilities exposed to the training framework.
+"""Truss-based graph utilities built on the decomposition:
 
-This is where the paper's technique becomes a first-class feature of the
-GNN/recsys pipelines (DESIGN.md §4):
-
-* ``truss_filter``       — keep only edges of the k-truss (cohesive-core
-                           training graph; the paper's visualization /
-                           fingerprinting use case as a data-prep op);
-* ``trussness_features`` — per-edge trussness as an input feature;
-* ``sampling_weights``   — trussness-proportional neighbor-sampling weights
-                           for the minibatch GNN sampler (strong ties first);
+* ``truss_filter``       — keep only edges of the k-truss (the paper's
+                           visualization / fingerprinting use case);
+* ``trussness_features`` — per-edge trussness as a normalized feature;
+* ``sampling_weights``   — trussness-proportional edge-sampling weights
+                           (strong ties first);
 * ``clique_upper_bound`` — k_max bound on the maximum clique (Section 7.4).
 """
 

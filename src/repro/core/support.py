@@ -345,7 +345,7 @@ def edge_support_jax(
 
     ``bucketed=True`` (default) runs the skew-aware per-bucket wedge scans;
     ``bucketed=False`` restores the single global-D scan (the seed behavior,
-    kept for benchmarks and as a fallback).
+    the reference the bucketed scans are tested against).
     """
     if g.m == 0:
         return jnp.zeros(0, jnp.int32)

@@ -1,18 +1,14 @@
-"""Synthetic graph generators (the data pipeline for the paper's workload
-and for the GNN shapes).  All host-side numpy; deterministic per seed.
+"""Synthetic graph generators for the paper's workload.  All host-side
+numpy; deterministic per seed.
 
 * ``rmat`` — power-law graphs (Kronecker / R-MAT), the shape of the paper's
   web/social datasets (heavy-tailed degrees, high clustering in cores);
 * ``erdos_renyi`` — flat-degree control;
 * ``planted_cliques`` — community graphs with known dense cores (ground
-  truth for truss-decomposition sanity: planted q-clique => q-truss);
-* ``mesh2d`` — triangulated grid (MeshGraphNet-like geometry);
-* per-shape GNN batch builders producing the static padded dict format.
+  truth for truss-decomposition sanity: planted q-clique => q-truss).
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 import numpy as np
 
@@ -60,73 +56,3 @@ def planted_cliques(n: int, n_cliques: int, clique_size: int,
     edges.append(np.stack([u, v], 1))
     return glib.canonical_edges(np.concatenate(edges), n)
 
-
-def mesh2d(rows: int, cols: int) -> tuple[int, np.ndarray, np.ndarray]:
-    """Triangulated grid: returns (n, edges, positions (n, 3))."""
-    n = rows * cols
-    idx = np.arange(n).reshape(rows, cols)
-    e = []
-    e.append(np.stack([idx[:, :-1].ravel(), idx[:, 1:].ravel()], 1))
-    e.append(np.stack([idx[:-1, :].ravel(), idx[1:, :].ravel()], 1))
-    e.append(np.stack([idx[:-1, :-1].ravel(), idx[1:, 1:].ravel()], 1))
-    edges = glib.canonical_edges(np.concatenate(e), n)
-    xy = np.stack(np.meshgrid(np.arange(rows), np.arange(cols), indexing="ij"),
-                  -1).reshape(n, 2).astype(np.float32)
-    pos = np.concatenate([xy, np.zeros((n, 1), np.float32)], 1)
-    return n, edges, pos
-
-
-# ---------------------------------------------------------------------------
-# GNN batch builders (static padded dict format of models/gnn)
-# ---------------------------------------------------------------------------
-
-def _directed(edges: np.ndarray) -> np.ndarray:
-    return np.concatenate([edges, edges[:, ::-1]]).astype(np.int32)
-
-
-def gnn_full_batch(n: int, edges: np.ndarray, d_feat: int, n_classes: int,
-                   seed: int = 0, positions: Optional[np.ndarray] = None,
-                   regression: bool = False) -> dict:
-    rng = np.random.default_rng(seed)
-    ei = _directed(edges)
-    batch = {
-        "node_feat": rng.standard_normal((n, d_feat)).astype(np.float32),
-        "edge_index": ei,
-        "edge_mask": np.ones(len(ei), bool),
-        "positions": (positions if positions is not None
-                      else rng.standard_normal((n, 3)).astype(np.float32)),
-    }
-    if regression:
-        batch["targets"] = rng.standard_normal(n).astype(np.float32)
-        batch["node_mask"] = np.ones(n, np.float32)
-    else:
-        batch["labels"] = rng.integers(0, n_classes, n).astype(np.int32)
-        batch["label_mask"] = (rng.random(n) < 0.5).astype(np.float32)
-    # MeshGraphNet extras
-    pos = batch["positions"]
-    rel = pos[ei[:, 1]] - pos[ei[:, 0]]
-    batch["edge_feat"] = np.concatenate(
-        [rel, np.linalg.norm(rel, axis=1, keepdims=True)], 1).astype(np.float32)
-    batch["targets_vec"] = rng.standard_normal((n, 3)).astype(np.float32)
-    return batch
-
-
-def gnn_molecule_batch(n_graphs: int, n_nodes: int, n_edges: int,
-                       d_feat: int, seed: int = 0) -> dict:
-    """Batched small graphs flattened into one disjoint padded graph."""
-    rng = np.random.default_rng(seed)
-    all_edges = []
-    for g in range(n_graphs):
-        e = erdos_renyi(n_nodes, n_edges // 2, seed + 7 * g + 1)
-        all_edges.append(_directed(e) + g * n_nodes)
-    ei = np.concatenate(all_edges).astype(np.int32)
-    n = n_graphs * n_nodes
-    b = gnn_full_batch(n, np.zeros((0, 2), np.int64), d_feat, 2, seed,
-                       regression=True)
-    b["edge_index"] = ei
-    b["edge_mask"] = np.ones(len(ei), bool)
-    pos = b["positions"]
-    rel = pos[ei[:, 1]] - pos[ei[:, 0]]
-    b["edge_feat"] = np.concatenate(
-        [rel, np.linalg.norm(rel, axis=1, keepdims=True)], 1).astype(np.float32)
-    return b

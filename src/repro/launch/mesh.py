@@ -1,12 +1,7 @@
-"""Production mesh definition (TPU v5e pods).
-
-Single pod: (data=16, model=16) = 256 chips.
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips — the 'pod' axis carries
-only data parallelism (gradient all-reduce over DCI), model parallelism
-stays inside a pod's ICI.
+"""Host meshes over the local devices for the truss engines' ``mesh=``.
 
 Defined as functions so importing this module never touches jax device
-state (the dry-run sets XLA_FLAGS before any jax import).
+state.
 """
 
 from __future__ import annotations
@@ -14,12 +9,6 @@ from __future__ import annotations
 import jax
 
 _AUTO = jax.sharding.AxisType.Auto
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(n: int | None = None, axes=("data", "model")):

@@ -33,7 +33,7 @@ def er_graph(rng, n=24, p=0.35):
 
 def rmat_graph(scale=5, edge_factor=6, seed=2):
     """Seeded power-law (R-MAT) graph — the paper's web/social shape at
-    test size; mirrors ``benchmarks/datasets.py``."""
+    test size."""
     from repro.data import graphgen
 
     n, edges = graphgen.rmat(scale, edge_factor, seed)

@@ -10,6 +10,8 @@ tmp+rename protocol must make safe.
 
 import os
 
+import jax
+import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
 import pytest
@@ -167,3 +169,33 @@ def test_structure_error_is_not_swallowed_by_fallback(tmp_path):
         ckpt.restore(d, {"phi": np.zeros(50)})
     assert issubclass(ckpt.CheckpointStructureError, ckpt.CheckpointError)
     assert issubclass(ckpt.CheckpointCorruptionError, ckpt.CheckpointError)
+
+
+# ------------------------------------------------------- pytree snapshots
+
+def test_checkpoint_roundtrip(tmp_path):
+    tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
+            "b": {"c": jnp.ones(4, jnp.bfloat16)},
+            "step": jnp.asarray(7, jnp.int32)}
+    d = str(tmp_path / "ck")
+    ckpt.save(d, 3, tree, {"note": "x"})
+    like = jax.tree.map(lambda x: np.zeros(x.shape, x.dtype), tree)
+    out, meta = ckpt.restore(d, like)
+    assert meta["note"] == "x"
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_checkpoint_keep_k(tmp_path):
+    d = str(tmp_path / "ck")
+    for s in range(5):
+        ckpt.save(d, s, {"x": jnp.zeros(1)}, keep=2)
+    assert ckpt.all_steps(d) == [3, 4]
+
+
+def test_checkpoint_atomic_tmp_ignored(tmp_path):
+    d = str(tmp_path / "ck")
+    ckpt.save(d, 1, {"x": jnp.zeros(1)})
+    os.makedirs(os.path.join(d, "step_0000000002.tmp"))  # simulated crash
+    assert ckpt.latest_step(d) == 1

@@ -4,8 +4,8 @@ One parametrized sweep pins every engine × partitioner × mesh
 configuration to the ``serial.alg2_truss`` oracle on the shared
 ``conformance_corpus`` graphs, and asserts the ``OocStats`` invariants
 that every out-of-core run must satisfy.  The in-memory engines (dense /
-frontier) ignore partitioner and mesh, so only their canonical
-configuration runs; the out-of-core engines sweep the full cross product.
+frontier) ignore partitioner and mesh, so each runs once; the out-of-core
+engines sweep the full partitioner × mesh cross product.
 
 The mesh configurations build over whatever devices the ambient process
 has — 1 locally, 8 in the CI step that sets
@@ -28,9 +28,14 @@ from tests.conftest import conformance_corpus
 CORPUS = conformance_corpus()
 _ORACLE = {name: alg2_truss(n, ce) for name, n, ce in CORPUS}
 
-ENGINES = ("dense", "frontier", "bottom-up", "top-down")
 PARTITIONERS = ("sequential", "random", "locality")
 MESHES = ("none", "devices", "devices2d")
+# (engine, partitioner, mesh) rows that run: the in-memory engines once,
+# the out-of-core engines over every partitioner and mesh
+MATRIX = ([(engine, "sequential", "none") for engine in ("dense", "frontier")]
+          + [(engine, partitioner, mesh_kind)
+             for engine in ("bottom-up", "top-down")
+             for partitioner in PARTITIONERS for mesh_kind in MESHES])
 
 
 def _mesh(kind):
@@ -75,13 +80,10 @@ def _check_ooc_stats(stats: OocStats, mesh, tag):
         assert stats.lane_shards == mesh.shape["data"], tag
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-@pytest.mark.parametrize("partitioner", PARTITIONERS)
-@pytest.mark.parametrize("mesh_kind", MESHES)
+@pytest.mark.parametrize("engine,partitioner,mesh_kind", MATRIX,
+                         ids=["-".join(row[::-1]) for row in MATRIX])
 def test_conformance_matrix(engine, partitioner, mesh_kind):
     in_memory = engine in ("dense", "frontier")
-    if in_memory and (partitioner != "sequential" or mesh_kind != "none"):
-        pytest.skip("in-memory engines ignore partitioner and mesh")
     mesh, axes = _mesh(mesh_kind)
     for name, n, ce in CORPUS:
         oracle = _ORACLE[name]
@@ -133,7 +135,8 @@ def test_conformance_drivers_direct(partitioner, mesh_kind):
 @pytest.mark.parametrize("engine", ("bottom-up", "top-down"))
 @pytest.mark.parametrize("store_kind", ("memory", "disk"))
 @pytest.mark.parametrize("partitioner", ("sequential", "locality"))
-def test_conformance_store_matrix(tmp_path, engine, store_kind,
+@pytest.mark.parametrize("name,n,ce", CORPUS, ids=[c[0] for c in CORPUS])
+def test_conformance_store_matrix(tmp_path, name, n, ce, engine, store_kind,
                                   partitioner):
     """``store=`` rows of the matrix (DESIGN.md §15): the same drivers over
     an InMemoryStore (behavioral no-op) and a ChunkedDiskStore (graph
@@ -141,45 +144,42 @@ def test_conformance_store_matrix(tmp_path, engine, store_kind,
     and the disk rows must show real chunk I/O in the OocStats counters."""
     from repro.core.store import ChunkedDiskStore, InMemoryStore
 
-    for i, (name, n, ce) in enumerate(CORPUS):
-        oracle = _ORACLE[name]
-        tag = ("store", engine, store_kind, partitioner, name)
-        if store_kind == "memory":
-            store = InMemoryStore()
-        else:
-            store = ChunkedDiskStore(str(tmp_path / f"s{i}"),
-                                     chunk_bytes=1 << 10)
-        with store, warnings.catch_warnings():
-            warnings.simplefilter("ignore", PartitionBudgetWarning)
-            phi, stats = truss_decompose(
-                n, ce, engine=engine, memory_budget=max(48, len(ce)),
-                partitioner=partitioner, store=store, with_stats=True)
-        assert (phi == oracle).all(), tag
-        assert verify_truss(n, ce, phi), tag
-        _check_ooc_stats(stats, None, tag)
-        if store_kind == "disk":
-            assert stats.chunk_writes > 0, tag
-            assert stats.bytes_spilled > 0, tag
-            assert stats.chunk_reads > 0, tag
-            total = stats.prefetch_hits + stats.prefetch_misses
-            assert total > 0, tag
-        else:
-            assert stats.chunk_writes == stats.chunk_reads == 0, tag
-            assert stats.bytes_spilled == 0, tag
+    tag = ("store", engine, store_kind, partitioner, name)
+    if store_kind == "memory":
+        store = InMemoryStore()
+    else:
+        store = ChunkedDiskStore(str(tmp_path / "store"),
+                                 chunk_bytes=1 << 10)
+    with store, warnings.catch_warnings():
+        warnings.simplefilter("ignore", PartitionBudgetWarning)
+        phi, stats = truss_decompose(
+            n, ce, engine=engine, memory_budget=max(48, len(ce)),
+            partitioner=partitioner, store=store, with_stats=True)
+    assert (phi == _ORACLE[name]).all(), tag
+    assert verify_truss(n, ce, phi), tag
+    _check_ooc_stats(stats, None, tag)
+    if store_kind == "disk":
+        assert stats.chunk_writes > 0, tag
+        assert stats.bytes_spilled > 0, tag
+        assert stats.chunk_reads > 0, tag
+        total = stats.prefetch_hits + stats.prefetch_misses
+        assert total > 0, tag
+    else:
+        assert stats.chunk_writes == stats.chunk_reads == 0, tag
+        assert stats.bytes_spilled == 0, tag
 
 
-def test_conformance_host_memory_budget_knob():
+@pytest.mark.parametrize("name,n,ce", CORPUS, ids=[c[0] for c in CORPUS])
+def test_conformance_host_memory_budget_knob(name, n, ce):
     """The one-knob spelling: ``host_memory_budget=`` builds a scratch
     ChunkedDiskStore internally and must reproduce the oracle."""
-    for name, n, ce in CORPUS:
-        oracle = _ORACLE[name]
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", PartitionBudgetWarning)
-            phi, stats = truss_decompose(
-                n, ce, engine="bottom-up", memory_budget=max(48, len(ce)),
-                host_memory_budget=1 << 16, with_stats=True)
-        assert (phi == oracle).all(), name
-        assert stats.chunk_writes > 0, name
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", PartitionBudgetWarning)
+        phi, stats = truss_decompose(
+            n, ce, engine="bottom-up", memory_budget=max(48, len(ce)),
+            host_memory_budget=1 << 16, with_stats=True)
+    assert (phi == _ORACLE[name]).all(), name
+    assert stats.chunk_writes > 0, name
 
 
 @pytest.mark.parametrize("engine", ("bottom-up", "top-down"))

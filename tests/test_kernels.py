@@ -95,76 +95,3 @@ class TestTriangleCount:
         S_ref = dense_support(A, block=64, interpret=True, use_kernel=False)
         np.testing.assert_allclose(np.asarray(S), np.asarray(S_ref))
 
-
-class TestFlashAttention:
-    @pytest.mark.parametrize("B,Hq,Hkv,S,D,win", [
-        (2, 4, 2, 256, 64, None),
-        (1, 8, 8, 128, 128, None),
-        (2, 4, 1, 256, 64, 96),
-        (1, 2, 2, 512, 32, 128),
-    ])
-    def test_vs_ref(self, rng, B, Hq, Hkv, S, D, win):
-        from repro.kernels.flash_attention import ref
-        from repro.kernels.flash_attention.ops import flash_attention
-        q = jnp.asarray(rng.standard_normal((B, Hq, S, D)).astype(np.float32))
-        k = jnp.asarray(rng.standard_normal((B, Hkv, S, D)).astype(np.float32))
-        v = jnp.asarray(rng.standard_normal((B, Hkv, S, D)).astype(np.float32))
-        o = flash_attention(q, k, v, window=win, bq=64, bk=64, interpret=True)
-        o_ref = ref.mha_reference(q, k, v, window=win)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_bf16(self, rng):
-        from repro.kernels.flash_attention import ref
-        from repro.kernels.flash_attention.ops import flash_attention
-        q = jnp.asarray(rng.standard_normal((1, 2, 128, 64))).astype(jnp.bfloat16)
-        k = jnp.asarray(rng.standard_normal((1, 2, 128, 64))).astype(jnp.bfloat16)
-        v = jnp.asarray(rng.standard_normal((1, 2, 128, 64))).astype(jnp.bfloat16)
-        o = flash_attention(q, k, v, bq=64, bk=64, interpret=True)
-        o_ref = ref.mha_reference(q, k, v)
-        np.testing.assert_allclose(
-            np.asarray(o, np.float32), np.asarray(o_ref, np.float32),
-            rtol=0.05, atol=0.05)
-
-    def test_chunked_jnp_paths(self, rng):
-        from repro.kernels.flash_attention import ref
-        from repro.models.attention import banded_attention, chunked_attention
-        q = jnp.asarray(rng.standard_normal((2, 128, 4, 32)).astype(np.float32))
-        k = jnp.asarray(rng.standard_normal((2, 128, 2, 32)).astype(np.float32))
-        v = jnp.asarray(rng.standard_normal((2, 128, 2, 32)).astype(np.float32))
-        t = lambda x: x.transpose(0, 2, 1, 3)
-        o_ref = t(ref.mha_reference(t(q), t(k), t(v), causal=True))
-        o_c = chunked_attention(q, k, v, q_chunk=32, k_chunk=64)
-        np.testing.assert_allclose(np.asarray(o_c), np.asarray(o_ref),
-                                   rtol=2e-5, atol=2e-5)
-        o_refw = t(ref.mha_reference(t(q), t(k), t(v), causal=True, window=48))
-        o_b = banded_attention(q, k, v, window=48, q_chunk=32)
-        np.testing.assert_allclose(np.asarray(o_b), np.asarray(o_refw),
-                                   rtol=2e-5, atol=2e-5)
-
-
-class TestEmbeddingBag:
-    @pytest.mark.parametrize("V,D,B,L,mode", [
-        (64, 18, 8, 10, "mean"), (128, 128, 16, 4, "sum"),
-        (32, 100, 4, 7, "mean"), (256, 64, 2, 100, "sum"),
-    ])
-    def test_vs_ref(self, rng, V, D, B, L, mode):
-        from repro.kernels.embedding_bag import ref
-        from repro.kernels.embedding_bag.ops import embedding_bag
-        tbl = jnp.asarray(rng.standard_normal((V, D)).astype(np.float32))
-        idx = jnp.asarray(rng.integers(0, V, (B, L)).astype(np.int32))
-        o = embedding_bag(tbl, idx, mode=mode, interpret=True)
-        o_ref = ref.embedding_bag(tbl, idx, mode=mode)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_bf16(self, rng):
-        from repro.kernels.embedding_bag import ref
-        from repro.kernels.embedding_bag.ops import embedding_bag
-        tbl = jnp.asarray(rng.standard_normal((64, 32))).astype(jnp.bfloat16)
-        idx = jnp.asarray(rng.integers(0, 64, (4, 8)).astype(np.int32))
-        o = embedding_bag(tbl, idx, mode="sum", interpret=True)
-        o_ref = ref.embedding_bag(tbl, idx, mode="sum")
-        np.testing.assert_allclose(np.asarray(o, np.float32),
-                                   np.asarray(o_ref, np.float32),
-                                   rtol=0.05, atol=0.05)

@@ -19,7 +19,7 @@ from repro.core.graph import build_graph, edge_id_lookup, undirected_csr
 from repro.core.maintain import EditBatch, truss_maintain
 from repro.core.peel import truss_decompose
 from repro.core.serial import alg2_truss
-from tests.conftest import clique_edges, conformance_corpus
+from tests.conftest import clique_edges, conformance_corpus, rmat_graph
 
 CORPUS = conformance_corpus()
 _PHI0 = {name: alg2_truss(n, ce) for name, n, ce in CORPUS}
@@ -92,6 +92,28 @@ def test_differential_mixed(name, n, ce):
     ins = [("insert", u, v) for u, v in _absent(rng, n, ce, 3)]
     steps = [s for pair in zip(dels, ins) for s in pair]   # interleaved
     _check(n, ce, _PHI0[name], steps)
+
+
+@pytest.mark.parametrize("name,n,ce", CORPUS, ids=[c[0] for c in CORPUS])
+def test_single_edge_edits_stay_local(name, n, ce):
+    """One delete and one insert re-peel less than the whole graph: the
+    candidate edges summed over every maintenance level stay below m."""
+    rng = np.random.default_rng(0)
+    steps = [("delete", *_existing(rng, n, ce, 1)[0]),
+             ("insert", *_absent(rng, n, ce, 1)[0])]
+    res = _check(n, ce, _PHI0[name], steps)
+    assert res.stats.edits_applied == 2
+    assert res.stats.affected_edges < len(ce)
+
+
+def test_eight_edit_batch_runs_region_peels():
+    n, ce = rmat_graph(scale=6, edge_factor=6, seed=2)
+    rng = np.random.default_rng(9)
+    steps = ([("delete", u, v) for u, v in _existing(rng, n, ce, 4)]
+             + [("insert", u, v) for u, v in _absent(rng, n, ce, 4)])
+    res = _check(n, ce, alg2_truss(n, ce), steps)
+    assert res.stats.edits_applied == 8
+    assert res.stats.maintain_levels > 0
 
 
 def test_insert_raises_trussness():

@@ -1,14 +1,13 @@
 """Batched out-of-core engine: partition batches, incremental maintenance,
-batched local peels (DESIGN.md §8) — against the serial oracle and the seed
-per-part path."""
+batched local peels (DESIGN.md §8) — against the serial oracle and a
+per-part reference peel."""
 
 import numpy as np
 import pytest
 
 from repro.core import graph as glib
-from repro.core.bottom_up import (OocStats, _local_truss,
-                                  bottom_up_decompose, lower_bounding,
-                                  partitioned_support)
+from repro.core.bottom_up import (OocStats, bottom_up_decompose,
+                                  lower_bounding, partitioned_support)
 from repro.core.partition import (build_partition_batch, ns_edge_lists,
                                   sequential_partition)
 from repro.core.peel import (PendingPeel, estimate_working_set,
@@ -16,7 +15,27 @@ from repro.core.peel import (PendingPeel, estimate_working_set,
                              truss_decompose)
 from repro.core.serial import alg2_truss
 from repro.core.support import edge_support_np, list_triangles, list_triangles_np
-from tests.conftest import clique_edges, random_graph
+from tests.conftest import clique_edges, random_graph, rmat_graph
+
+
+def _local_truss(sub_edges: np.ndarray, n: int) -> np.ndarray:
+    """Trussness of every edge of one NS subgraph, peeled on its own: the
+    reference each packed lane slice must match."""
+    import jax.numpy as jnp
+
+    from repro.core.peel import peel_classes
+    from repro.core.support import support_from_triangle_list
+
+    g = glib.build_graph(n, sub_edges)
+    if g.m == 0:
+        return np.zeros(0, np.int64)
+    tris = list_triangles_np(g)
+    sup = support_from_triangle_list(tris, g.m).astype(np.int32)
+    if len(tris) == 0:
+        tris = np.full((1, 3), g.m, np.int32)
+    phi, _ = peel_classes(jnp.asarray(sup), jnp.asarray(tris),
+                          jnp.ones(g.m, bool))
+    return np.asarray(phi).astype(np.int64)
 
 
 # ---------------------------------------------------------------------------
@@ -68,8 +87,8 @@ def test_ns_edge_lists_matches_reference(rng):
 
 def test_bucket_padding_never_contributes_support(rng):
     """Padded lanes and padded edge slots are inert: zero support in, zero
-    phi out; every packed part slice reproduces the seed per-part local
-    peel exactly."""
+    phi out; every packed part slice reproduces a standalone peel of its
+    NS exactly."""
     n = 40
     ce = glib.canonical_edges(random_graph(rng, n, 0.3), n)
     g = glib.build_graph(n, ce)
@@ -100,14 +119,30 @@ def test_bucket_padding_never_contributes_support(rng):
             # 3 * (real triangle count) — padding contributed nothing
             n_tri = int((bucket.tris[lane][:, 0] < bucket.cap_e).sum())
             assert int(bucket.sup[lane].sum()) == 3 * n_tri
-            # every part slice packed into the lane equals the seed
-            # per-part local peel of that NS
+            # every part slice packed into the lane equals a standalone
+            # peel of that NS
             for p in np.unique(bucket.part_of[lane][real]):
                 sl = bucket.part_of[lane] == p
                 ref = _local_truss(g.edges[bucket.edge_ids[lane][sl]], g.n)
                 assert (phi_b[lane][sl] == ref).all()
                 seen_parts.add(int(p))
     assert len(seen_parts) == batch.n_parts
+
+
+def test_bottom_up_lane_padding_waste_rmat10():
+    """Waste-aware lane packing on one device: bottom-up on rmat-10 at a
+    1/32 budget pads less than the 0.674 share of lane slots the packer
+    wasted before it, with φ equal to the oracle."""
+    import warnings
+
+    from repro.core.partition import PartitionBudgetWarning
+
+    n, ce = rmat_graph(scale=10, edge_factor=6, seed=7)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", PartitionBudgetWarning)
+        res = bottom_up_decompose(n, ce, max(len(ce) // 32, 256))
+    assert (res.phi == alg2_truss(n, ce)).all()
+    assert res.stats.padding_waste < 0.674
 
 
 def test_local_threshold_peel_matches_dense(rng):
@@ -261,18 +296,6 @@ def test_pending_peel_result_cached_on_success():
     assert handle.new_compile and not handle.sharded
 
 
-def test_batched_equals_perpart_engine(rng):
-    n = 36
-    ce = glib.canonical_edges(random_graph(rng, n, 0.3), n)
-    budget = max(8, len(ce) // 4)
-    res_b = bottom_up_decompose(n, ce, budget)
-    res_p = bottom_up_decompose(n, ce, budget, engine="perpart")
-    assert (res_b.phi == res_p.phi).all()
-    sup_b = partitioned_support(n, ce, budget)
-    sup_p = partitioned_support(n, ce, budget, engine="perpart")
-    assert (sup_b == sup_p).all()
-
-
 # ---------------------------------------------------------------------------
 # spilled-triangle streaming: reload peak bounded below the spilled total
 # ---------------------------------------------------------------------------
@@ -299,3 +322,36 @@ def test_spilled_triangle_reload_peak_bounded(tmp_path, rng):
     assert s.tri_rescans_avoided > 0          # spilled rounds actually ran
     assert s.tri_spill_rows > 0
     assert 0 < s.tri_reload_peak_rows < s.tri_spill_rows
+
+
+def test_disk_store_at_an_eighth_of_the_graph(tmp_path):
+    """Bottom-up on rmat-10 with the graph behind a ChunkedDiskStore held
+    to 1/8 of the graph's bytes: φ equals the oracle, the graph really
+    spills and streams back, the prefetcher serves at least half of the
+    chunk requests, and residency never passes the budget."""
+    import warnings
+
+    from repro.core.partition import PartitionBudgetWarning
+    from repro.core.store import ChunkedDiskStore
+
+    n, ce = rmat_graph(scale=10, edge_factor=6, seed=7)
+    g = glib.build_graph(n, ce)
+    graph_bytes = sum(
+        int(getattr(g, a).nbytes)
+        for a in ("edges", "deg", "rank", "src", "dst", "indptr", "nbrs",
+                  "nbr_eid"))
+    host_budget = graph_bytes // 8
+    with ChunkedDiskStore(str(tmp_path / "store"),
+                          host_memory_budget=host_budget,
+                          chunk_bytes=max(host_budget // 16, 4096)) as store:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", PartitionBudgetWarning)
+            res = bottom_up_decompose(n, ce, max(len(ce) // 32, 256),
+                                      store=store)
+        peak = store.stats.peak_resident_bytes
+    assert (res.phi == alg2_truss(n, ce)).all()
+    s = res.stats
+    assert s.bytes_spilled > 0
+    assert s.chunk_reads > 0 and s.chunk_writes > 0
+    assert s.prefetch_hit_rate >= 0.5, s.prefetch_hit_rate
+    assert peak <= host_budget, (peak, host_budget)

@@ -86,12 +86,6 @@ def test_truss_decompose_mesh_dispatch(rng, mesh):
         assert st.sharded_rounds > 0, engine
 
 
-def test_mesh_rejected_on_perpart_engine(rng, mesh):
-    ce, n = _graph(rng)
-    with pytest.raises(ValueError, match="batched engine"):
-        bottom_up_decompose(n, ce, 32, engine="perpart", mesh=mesh)
-
-
 def test_bucket_sharded_matches_single_device(rng, mesh):
     """Direct bucket-level equality, including uneven lane counts: with
     ``pad_lanes_pow2=False`` the lane count is whatever the packer produced,

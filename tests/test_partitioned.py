@@ -17,15 +17,13 @@ def _graph(rng, n=40, p=0.3):
     return glib.canonical_edges(random_graph(rng, n, p), n), n
 
 
-@pytest.mark.parametrize("engine", ["batched", "perpart"])
 @pytest.mark.parametrize("partitioner", ["sequential", "random", "locality"])
 @pytest.mark.parametrize("budget_frac", [0.2, 0.5])
-def test_bottom_up_exact(rng, partitioner, budget_frac, engine):
+def test_bottom_up_exact(rng, partitioner, budget_frac):
     ce, n = _graph(rng)
     oracle = alg2_truss(n, ce)
     budget = max(8, int(len(ce) * budget_frac))
-    res = bottom_up_decompose(n, ce, budget, partitioner=partitioner,
-                              engine=engine)
+    res = bottom_up_decompose(n, ce, budget, partitioner=partitioner)
     assert (res.phi == oracle).all()
     assert res.kmax == oracle.max()
 

@@ -89,6 +89,24 @@ def test_top_down_interrupt_resume(tmp_path, name, n, ce, site, where, nth):
     assert (res.phi == _ORACLE[name]).all(), name
 
 
+@pytest.mark.parametrize("name,n,ce", CORPUS, ids=[c[0] for c in CORPUS])
+def test_journal_adds_no_device_work(tmp_path, name, n, ce):
+    """Journaling every round is host I/O only: a journaled bottom-up run
+    writes snapshots, and launches, uploads and rounds exactly what the
+    unjournaled run does (the counters ``ooc_batches`` and ``h2d_bytes``
+    read)."""
+    with _quiet():
+        plain = bottom_up_decompose(n, ce, budget=BUDGET)
+        journaled = bottom_up_decompose(
+            n, ce, budget=BUDGET, checkpoint_dir=str(tmp_path / "ckpt"),
+            checkpoint_every=1)
+    assert (journaled.phi == _ORACLE[name]).all(), name
+    assert journaled.stats.checkpoints > 0, name
+    for counter in ("batches", "h2d_bytes", "rounds"):
+        assert (getattr(journaled.stats, counter)
+                == getattr(plain.stats, counter)), (name, counter)
+
+
 def test_resume_empty_dir_is_fresh_run(tmp_path):
     name, n, ce = CORPUS[0]
     with _quiet():

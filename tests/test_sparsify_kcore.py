@@ -1,5 +1,5 @@
 """Coverage for the small exposed modules: ``sparsify`` (truss-based graph
-utilities for the training pipelines) and ``kcore`` (the paper's Section
+utilities) and ``kcore`` (the paper's Section
 7.4 comparison structure)."""
 
 import numpy as np
@@ -164,3 +164,15 @@ def test_core_star_and_path():
     assert core.max() == 1          # star + path are 1-degenerate
     cmax, edges_c = cmax_core(n, ce)
     assert cmax == 1 and len(edges_c) == len(ce)
+
+
+def test_truss_sparsify_features():
+    from repro.data import graphgen
+    edges = graphgen.planted_cliques(60, 2, 6, 60, seed=0)
+    t6 = truss_filter(60, edges, 6)
+    assert len(t6) >= 2 * 15 - 15  # at least one clique survives
+    _, feats = trussness_features(60, edges)
+    assert feats.min() >= 0 and feats.max() <= 1
+    w = sampling_weights(60, edges)
+    assert abs(w.sum() - 1.0) < 1e-5
+    assert clique_upper_bound(60, edges) >= 6

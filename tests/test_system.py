@@ -1,8 +1,5 @@
 """End-to-end behaviour tests for the paper's system."""
 
-import numpy as np
-
-from repro.core import graph as glib
 from repro.core.bottom_up import bottom_up_decompose
 from repro.core.peel import truss_decompose
 from repro.core.serial import alg2_truss
@@ -22,28 +19,3 @@ def test_end_to_end_decomposition_paths_agree():
     for k in td.classes:
         assert ((td.phi == k) == (oracle == k)).all()
 
-
-def test_end_to_end_training_converges():
-    """Tiny LM through the full stack (data, model, optimizer, loop)."""
-    import jax
-
-    from repro.configs.reduced import make_reduced
-    from repro.optim import adamw
-
-    cfg, init_fn, loss_fn, batch_fn = make_reduced("granite-8b")
-    params = init_fn()
-    state = adamw.init_state(params)
-    ocfg = adamw.AdamWConfig(lr=5e-3, warmup_steps=2, total_steps=30)
-
-    @jax.jit
-    def step(params, state, batch):
-        loss, g = jax.value_and_grad(loss_fn)(params, batch)
-        params, state, _ = adamw.update(ocfg, params, state, g)
-        return params, state, loss
-
-    losses = []
-    for s in range(12):
-        params, state, loss = step(params, state, batch_fn(s))
-        losses.append(float(loss))
-    assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0]

@@ -661,7 +661,9 @@ def test_cli_exit_codes_and_json(tmp_path):
 def test_self_run_repo_is_clean():
     """The CI gate: src/repro checks clean modulo explicit allowlists."""
     report = analysis.check_paths([str(REPO_ROOT / "src" / "repro")])
-    assert report.files_checked > 50
+    # every module of the package was walked
+    assert report.files_checked == len(
+        list((REPO_ROOT / "src" / "repro").rglob("*.py")))
     assert [f.render() for f in report.errors] == []
     # every allowlist that exists carries a rationale (TRK100 enforces it,
     # but pin the invariant directly too)
@@ -692,29 +694,6 @@ def test_make_host_mesh_rejects_zero_devices():
     from repro.launch.mesh import make_host_mesh
     with pytest.raises(ValueError, match="positive"):
         make_host_mesh(0)
-
-
-def test_prefill_rejects_max_seq_shorter_than_prompt():
-    import jax.numpy as jnp
-    from repro.models.transformer import prefill
-    tokens = jnp.zeros((1, 4), jnp.int32)
-    with pytest.raises(ValueError, match="max_seq"):
-        prefill({}, tokens, None, max_seq=2)
-
-
-def test_flash_attention_kernel_rejects_bad_tiles_loudly():
-    import jax.numpy as jnp
-    from repro.kernels.flash_attention.kernel import (VMEM_BUDGET_BYTES,
-                                                      flash_attention_kernel,
-                                                      kernel_vmem_bytes)
-    q = jnp.zeros((1, 4, 6, 8), jnp.float32)   # s=6 not divisible by bq=4
-    k = v = jnp.zeros((1, 2, 6, 8), jnp.float32)
-    with pytest.raises(ValueError, match="divide"):
-        flash_attention_kernel(q, k, v, bq=4, bk=2, interpret=True)
-    bad_heads = jnp.zeros((1, 3, 6, 8), jnp.float32)
-    with pytest.raises(ValueError, match="kv heads"):
-        flash_attention_kernel(q, bad_heads, bad_heads, interpret=True)
-    assert kernel_vmem_bytes(512, 512, 128) < VMEM_BUDGET_BYTES
 
 
 def test_triangle_count_kernel_rejects_bad_tiles_loudly():
